@@ -42,6 +42,8 @@ ALL = {
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     names = sys.argv[1:] or list(ALL)
     for name in names:
         t0 = time.time()

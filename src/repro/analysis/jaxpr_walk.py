@@ -7,9 +7,8 @@ shard_map bodies, and pallas_call kernel jaxprs — tracking whether the
 current eqn sits inside a Pallas kernel body (dots inside a kernel are
 the kernel's own MXU tiles, not XLA fallbacks).
 
-The traversal is duck-typed (`hasattr(x, "eqns") / hasattr(x, "jaxpr")`)
-rather than isinstance-based so it survives the jax.core ->
-jax.extend.core move (JAX 0.4.x straddles both).
+The traversal is duck-typed (`hasattr(x, "eqns") / hasattr(x, "jaxpr")`):
+a param holding any jaxpr-like object is descended into.
 """
 from __future__ import annotations
 
@@ -18,10 +17,7 @@ from typing import Dict, Iterator, List, Tuple
 
 import jax
 
-try:
-    from jax.extend import core as _jcore
-except ImportError:   # pragma: no cover — older JAX
-    from jax import core as _jcore
+from jax.extend import core as _jcore
 
 JAXPR_TYPES = (_jcore.Jaxpr, _jcore.ClosedJaxpr)
 

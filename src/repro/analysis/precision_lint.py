@@ -409,12 +409,11 @@ def lint_cell(arch: str, shape: str, mesh, *,
     applicable pass.  A build or trace failure is itself an error
     finding — the lint never crashes the sweep."""
     from repro.launch import specs as S
-    from repro.launch.mesh import enter_mesh
     from repro.models.transformer import init_lm
 
     cell = cell_id or f"{arch}/{shape}"
     findings: List[Finding] = []
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         try:
             built = S.build_cell(arch, shape, mesh, overrides=overrides)
         except Exception as e:  # noqa: BLE001

@@ -12,8 +12,9 @@ scratch_shapes (`kernels/fused_quant_matmul/kernel.py`,
  * the attention kernels materialize per-(q-tile, kv-stripe) score/P
    tiles in vector registers / VMEM; the model charges one f32 + one fp8
    (bq, bkv) tile forward and two of each backward (dP and dS chains);
- * SMEM operands (scales, seeds) and (1, 1) amax tiles are charged at
-   their true byte size (negligible but honest);
+ * SMEM operands (scales, seeds) are charged at their true byte size;
+   each kernel's observation output (amaxes, health counts) is one
+   (8, 128) f32 stats block per grid cell (STATS_TILE_BYTES);
  * head_dim is padded to LANE (128) exactly as the ops-layer padding
    contract does before the kernel sees it.
 
@@ -37,6 +38,7 @@ from repro.kernels.autotune import LANE, TQ
 
 VMEM_BYTES = 16 * 1024 * 1024   # per-core VMEM budget the model fits into
 DMA_BUF = 2                     # grid-pipeline double buffering factor
+STATS_TILE_BYTES = 8 * 128 * 4  # one (8, 128) f32 observation block
 
 
 def _budget(budget: Optional[int]) -> int:
@@ -79,18 +81,18 @@ class VmemEstimate:
 
 # -------------------------------------------------------------- fused GEMM
 def gemm_vmem(bm: int, bk: int, bn: int, *, dims: str = "nn",
-              with_amax: bool = True, with_counts: bool = False,
+              with_amax: bool = True,
               budget: Optional[int] = None) -> VmemEstimate:
     """Fused quantize-epilogue GEMM (and the plain fp8_matmul, whose
     working set is a strict subset): fp8 a/b blocks + u8 SR-bits block in,
-    fp8 out block + scalar amax/health tiles out, one (bm, bn) f32
+    fp8 out block + the amax/health stats block out, one (bm, bn) f32
     accumulator scratch.  Layout transposes (nn/nt/tn) permute block
     dims, not bytes."""
     a_blk = bm * bk                       # fp8, 1 byte
     b_blk = bk * bn
     rand_blk = bm * bn                    # uint8 SR bits
     out_blk = bm * bn                     # fp8 payload
-    tiles = (4 if with_amax else 0) + (2 * 4 if with_counts else 0)
+    tiles = STATS_TILE_BYTES if with_amax else 0   # counts share it
     parts = {
         "in_blocks_x2": DMA_BUF * (a_blk + b_blk + rand_blk),
         "out_blocks_x2": DMA_BUF * (out_blk + tiles),
@@ -102,19 +104,17 @@ def gemm_vmem(bm: int, bk: int, bn: int, *, dims: str = "nn",
 
 # --------------------------------------------------------------- attention
 def attn_fwd_vmem(block_q: int, block_kv: int, head_dim: int, *,
-                  mask_mode: str = "causal", with_counts: bool = False,
+                  mask_mode: str = "causal",
                   budget: Optional[int] = None) -> VmemEstimate:
     """One-pass fwd kernel, grid (B, H, nq, nk): fp8 q/k/v blocks in
-    (+ per-stripe mask block for kv/chunk modes), bf16 o block + scalar
-    amax tiles out, (bq, 1) m/l + (bq, dp) f32 accumulator scratch, and
+    (+ per-stripe int32 mask block for kv/chunk modes), bf16 o block +
+    the stats block out, (bq, 1) m/l + (bq, dp) f32 accumulator scratch, and
     the transient (bq, bkv) score (f32) + P (fp8) tiles."""
     bq, bkv, dp = int(block_q), int(block_kv), _pad_lane(head_dim)
     mask_blk = 0
-    if mask_mode == "kv":
-        mask_blk = bkv                     # bool/int8 kv-mask stripe
-    elif mask_mode == "chunk":
-        mask_blk = bkv * 4                 # int32 slot-position stripe
-    out_tiles = 2 * 4 + (2 * 3 * 4 if with_counts else 0)
+    if mask_mode in ("kv", "chunk"):
+        mask_blk = bkv * 4                 # int32 validity / slot positions
+    out_tiles = STATS_TILE_BYTES           # amaxes and counts share it
     parts = {
         "in_blocks_x2": DMA_BUF * (bq * dp + 2 * bkv * dp + mask_blk),
         "out_blocks_x2": DMA_BUF * (bq * dp * 2 + out_tiles),
@@ -128,13 +128,12 @@ def attn_fwd_vmem(block_q: int, block_kv: int, head_dim: int, *,
 
 
 def attn_bwd_dq_vmem(block_q: int, block_kv: int, head_dim: int, *,
-                     with_counts: bool = False,
                      budget: Optional[int] = None) -> VmemEstimate:
     """dQ kernel, grid (B, H, nq, 4*nk): fp8 q/k/v/do blocks in, f32 dq
-    block + (bq, 1) m/l/rd statistics + amax tiles out, 3x (bq, 1) +
+    block + (bq, 1) m/l/rd statistics + the stats block out, 3x (bq, 1) +
     (bq, dp) f32 scratch, transient score/P and dP/dS tiles."""
     bq, bkv, dp = int(block_q), int(block_kv), _pad_lane(head_dim)
-    out_tiles = 2 * 4 + (2 * 3 * 4 if with_counts else 0)
+    out_tiles = STATS_TILE_BYTES
     parts = {
         "in_blocks_x2": DMA_BUF * (2 * bq * dp + 2 * bkv * dp),
         "out_blocks_x2": DMA_BUF * (bq * dp * 4 + 3 * bq * 4 + out_tiles),
@@ -166,18 +165,16 @@ def attn_bwd_dkv_vmem(block_q: int, block_kv: int, head_dim: int, *,
 
 
 def attn_vmem(kind: str, block_q: int, block_kv: int, head_dim: int, *,
-              mask_mode: str = "causal", with_counts: bool = False,
+              mask_mode: str = "causal",
               budget: Optional[int] = None) -> VmemEstimate:
     """Worst-case estimate for an attention pass: the fwd kernel, or the
     larger of the two backward kernels (bwd block_q below TQ is lifted to
     TQ exactly as the ops layer does)."""
     if kind == "fwd":
         return attn_fwd_vmem(block_q, block_kv, head_dim,
-                             mask_mode=mask_mode, with_counts=with_counts,
-                             budget=budget)
+                             mask_mode=mask_mode, budget=budget)
     bq = max(int(block_q), TQ)
-    ests = (attn_bwd_dq_vmem(bq, block_kv, head_dim,
-                             with_counts=with_counts, budget=budget),
+    ests = (attn_bwd_dq_vmem(bq, block_kv, head_dim, budget=budget),
             attn_bwd_dkv_vmem(bq, block_kv, head_dim, budget=budget))
     return max(ests, key=lambda e: e.total_bytes)
 

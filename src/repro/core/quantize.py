@@ -51,12 +51,56 @@ _F16_MAG_MASK = 0x7FFF
 _F16_SIGN_MASK = 0x8000
 
 
-def _f16_bits(x: Array) -> Array:
-    return jax.lax.bitcast_convert_type(x.astype(jnp.float16), jnp.uint16)
+def _f16_bits_i32(x: Array) -> Array:
+    """IEEE f32 -> f16 round-to-nearest-even, returned as the f16 bit pattern
+    in an int32 — bit-identical to `x.astype(float16)`, but in 32-bit
+    integer and f32 ops only: Mosaic cannot lower an f32 -> f16 cast on
+    v5e, so the Pallas epilogues reach fp16 bit patterns this way.
+
+    |x| is rounded onto the f16 grid in f32 (the ulp is a power of two and
+    the multiple fits the f32 mantissa, so both scalings are exact and
+    `round` is the single rounding); the on-grid value's f16 pattern is
+    then read off its f32 bits (normals) or off `q * 2**24` (subnormals).
+    Overflow rounds to inf; NaN keeps its top payload bits, quiet bit set."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    sign = (b >> 16) & jnp.int32(_F16_SIGN_MASK)
+    ab = b & jnp.int32(0x7FFFFFFF)
+    ax = jax.lax.bitcast_convert_type(ab, jnp.float32)
+    # Biased f32 exponent, floored at f16's min normal (2**-14 -> 113).
+    e = jnp.maximum(ab >> 23, jnp.int32(113))
+    ulp = jax.lax.bitcast_convert_type((e - 10) << 23, jnp.float32)
+    inv_ulp = jax.lax.bitcast_convert_type((264 - e) << 23, jnp.float32)
+    q = jnp.round(ax * inv_ulp) * ulp
+    qb = jax.lax.bitcast_convert_type(q, jnp.int32)
+    normal = (qb - jnp.int32(112 << 23)) >> 13
+    sub = jax.lax.bitcast_convert_type(q * jnp.float32(2.0 ** 24)
+                                       + jnp.float32(2.0 ** 23),
+                                       jnp.int32) & jnp.int32(0x7FF)
+    h = jnp.where(q >= jnp.float32(2.0 ** -14), normal, sub)
+    h = jnp.where(q >= jnp.float32(65536.0), jnp.int32(_F16_EXP_MASK), h)
+    nan = jnp.int32(_F16_EXP_MASK | 0x0200) | ((ab >> 13) & jnp.int32(0x3FF))
+    h = jnp.where(ab > jnp.int32(0x7F800000), nan, h)
+    return sign | h
 
 
-def _bits_f16(b: Array) -> Array:
-    return jax.lax.bitcast_convert_type(b.astype(jnp.uint16), jnp.float16)
+def _fp8_from_f16_bits(h: Array, fmt: FloatFormat) -> Array:
+    """fmt.dtype values of prescaled, on-grid fp16 patterns held in an int32
+    (the twiddle's output). The prescale aligns the two exponent fields, so
+    the fp8 byte is the sign plus the pattern's top bits; the specials follow
+    the f16 -> fp8 storage cast: an e5m2 NaN is 0x7F, and the inf-less fn
+    formats (e4m3) turn any non-finite value into a NaN that keeps its
+    sign."""
+    spec = sr_spec(fmt)
+    sign = (h >> 8) & jnp.int32(0x80)
+    mag = h & jnp.int32(_F16_MAG_MASK)
+    byte = sign | (mag >> spec.drop_bits)
+    if fmt.has_inf:
+        byte = jnp.where(mag > jnp.int32(_F16_EXP_MASK), jnp.int32(0x7F),
+                         byte)
+    else:
+        byte = jnp.where(mag >= jnp.int32(_F16_EXP_MASK),
+                         sign | jnp.int32(0x7F), byte)
+    return jax.lax.bitcast_convert_type(byte.astype(jnp.uint8), fmt.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -168,34 +212,41 @@ def sr_spec(fmt: FloatFormat) -> SRSpec:
                   ovf_bits=_F16_EXP_MASK if fmt.has_inf else 0x7E00)
 
 
-def sr_fp8_from_bits(h_bits: Array, rand: Array, fmt: FloatFormat = E5M2, *,
-                     saturate: bool = True) -> Array:
-    """Exact fp8 stochastic rounding given *prescaled* fp16 bit patterns plus
-    random bits (only the low `drop_bits` are used; masking a wider uniform
-    draw is fine). Pure uint16 math — shared verbatim with the Pallas
-    kernels (ref oracles and kernel bodies all call this). The result is the
-    prescaled fp16 pattern; undo the prescale before casting to fmt.dtype
-    (`sr_fp8_via_f16` does both ends).
-    """
-    spec = sr_spec(fmt)
-    mask = jnp.uint16((1 << spec.drop_bits) - 1)
-    h_bits = h_bits.astype(jnp.uint16)
-    sign = h_bits & _F16_SIGN_MASK
-    mag = h_bits & _F16_MAG_MASK
-    finite = mag < _F16_EXP_MASK
-    bumped = mag + (rand.astype(jnp.uint16) & mask)
+def _sr_twiddle(h_bits: Array, rand: Array, spec: SRSpec, *,
+                saturate: bool) -> Array:
+    """The SR bit-twiddle on prescaled fp16 patterns held in int32 (the
+    sums stay below 2**16, so this is the uint16 math in wider lanes)."""
+    mask = jnp.int32((1 << spec.drop_bits) - 1)
+    sign = h_bits & jnp.int32(_F16_SIGN_MASK)
+    mag = h_bits & jnp.int32(_F16_MAG_MASK)
+    finite = mag < jnp.int32(_F16_EXP_MASK)
+    bumped = mag + (rand.astype(jnp.int32) & mask)
     trunc = bumped & ~mask
     if saturate:
-        trunc = jnp.minimum(trunc, jnp.uint16(spec.max_bits))
+        trunc = jnp.minimum(trunc, jnp.int32(spec.max_bits))
     else:
         # Rounding up past max normal overflows: to the inf pattern for IEEE
         # formats (e5m2: 0x7B00 + 0x100 lands exactly on 0x7C00), to a NaN
         # pattern for the inf-less fn formats (e4m3).
-        trunc = jnp.where(trunc > jnp.uint16(spec.max_bits),
-                          jnp.uint16(spec.ovf_bits), trunc)
-    out_mag = jnp.where(finite, trunc, mag & ~mask | (mag & jnp.uint16(0x0200)))
+        trunc = jnp.where(trunc > jnp.int32(spec.max_bits),
+                          jnp.int32(spec.ovf_bits), trunc)
+    out_mag = jnp.where(finite, trunc, mag & ~mask | (mag & jnp.int32(0x0200)))
     # (non-finite: preserve inf/nan; keep a nan-signalling mantissa bit)
     return sign | out_mag
+
+
+def sr_fp8_from_bits(h_bits: Array, rand: Array, fmt: FloatFormat = E5M2, *,
+                     saturate: bool = True) -> Array:
+    """Exact fp8 stochastic rounding given *prescaled* fp16 bit patterns plus
+    random bits (only the low `drop_bits` are used; masking a wider uniform
+    draw is fine). Pure integer math — the same twiddle the Pallas kernel
+    bodies run through `sr_fp8_via_f16`. The result is the prescaled uint16
+    fp16 pattern; undo the prescale before casting to fmt.dtype
+    (`sr_fp8_via_f16` does both ends).
+    """
+    out = _sr_twiddle(h_bits.astype(jnp.int32), rand, sr_spec(fmt),
+                      saturate=saturate)
+    return out.astype(jnp.uint16)
 
 
 def sr_e5m2_from_bits(h_bits: Array, rand8: Array, *,
@@ -207,8 +258,9 @@ def sr_e5m2_from_bits(h_bits: Array, rand8: Array, *,
 def sr_fp8_via_f16(x: Array, rand: Array, fmt: FloatFormat = E5M2, *,
                    saturate: bool = True) -> Array:
     """Stochastically round `x` into fmt.dtype via the exact fp16 bit-twiddle
-    (prescale -> twiddle -> unscale -> storage cast). `rand` supplies the
-    random bits (uint; low `sr_spec(fmt).drop_bits` used)."""
+    (prescale -> f16 pattern -> twiddle -> fp8 byte), in 32-bit ops only so
+    the Pallas epilogues lower on v5e. `rand` supplies the random bits
+    (uint; low `sr_spec(fmt).drop_bits` used)."""
     spec = sr_spec(fmt)
     if saturate:
         # Clamp before the f16 step so |x| beyond fp16 range cannot escape to
@@ -219,14 +271,8 @@ def sr_fp8_via_f16(x: Array, rand: Array, fmt: FloatFormat = E5M2, *,
         x = jnp.where(jnp.isnan(x), x, jnp.clip(x, lo, hi))
     if spec.pre_exp:
         x = x * jnp.asarray(2.0 ** spec.pre_exp, x.dtype)
-    h = x.astype(jnp.float16)
-    out_bits = sr_fp8_from_bits(_f16_bits(h), rand, fmt, saturate=saturate)
-    out = _bits_f16(out_bits)
-    if spec.pre_exp:
-        # Exact: every prescaled grid point times 2**-pre_exp is on the fmt
-        # grid and representable in fp16 (max_normal <= 448 <= f16 max).
-        out = out * jnp.float16(2.0 ** -spec.pre_exp)
-    return out.astype(fmt.dtype)
+    out_bits = _sr_twiddle(_f16_bits_i32(x), rand, spec, saturate=saturate)
+    return _fp8_from_f16_bits(out_bits, fmt)
 
 
 def quantize_sr_fp8(x: Array, key: Array, fmt: FloatFormat = E5M2, *,

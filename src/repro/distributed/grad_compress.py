@@ -64,29 +64,30 @@ def fp8_allreduce_mean(y: Array, *, axis_name: str,
     Returns (mean, dequantized_local_contribution) — the caller computes the
     error-feedback residual as y - dequantized_local_contribution.
     """
-    # jax.lax.axis_size is newer-JAX; psum of a python 1 is the classic
-    # spelling and constant-folds to a static int under shard_map/pmap.
-    n = jax.lax.axis_size(axis_name) \
-        if hasattr(jax.lax, "axis_size") else jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     scale = jax.lax.pmax(_amax(y), axis_name) / fmt.max_normal
     scale = jnp.maximum(scale, 1e-30)
     q = quantize_rne(y / scale, fmt, saturate=True)          # local fp8 grid
 
-    flat = _to_wire(q, fmt).reshape(-1)
-    pad = (-flat.shape[0]) % n
+    # Split rows of the leaf's last axis over the devices: the payload keeps
+    # its tiled 2-D layout (a 1-D view of a large fp8 array takes the TPU
+    # compiler minutes to lay out). Each element still meets the same n
+    # contributions, summed in device order, whichever device holds it.
+    rows = q.reshape(-1, q.shape[-1]) if q.ndim > 1 else q.reshape(1, -1)
+    pad = (-rows.shape[0]) % n
     if pad:
-        flat = jnp.pad(flat, (0, pad))
-    chunks = flat.reshape(n, -1)
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    chunks = _to_wire(rows, fmt).reshape(n, -1, rows.shape[-1])
     # reduce-scatter leg: all_to_all moves fp8 (1B/elt on the wire)
     recv = jax.lax.all_to_all(chunks, axis_name, split_axis=0,
                               concat_axis=0, tiled=False)
-    partial = recv.astype(jnp.float32).sum(axis=0) * scale   # (chunk,) f32
+    partial = recv.astype(jnp.float32).sum(axis=0) * scale   # rows/n x cols
     # all-gather leg: re-quantize the reduced shard, 1B/elt again
     scale2 = jnp.maximum(jax.lax.pmax(_amax(partial), axis_name)
                          / fmt.max_normal, 1e-30)
     q2 = quantize_rne(partial / scale2, fmt, saturate=True)
-    gathered = jax.lax.all_gather(_to_wire(q2, fmt), axis_name)  # (n, chunk)
-    total = gathered.astype(jnp.float32).reshape(-1) * scale2
+    gathered = jax.lax.all_gather(_to_wire(q2, fmt), axis_name)
+    total = gathered.astype(jnp.float32).reshape(rows.shape) * scale2
     if pad:
         total = total[:-pad]
     mean = (total / n).reshape(y.shape)
@@ -139,7 +140,7 @@ def make_compressed_dp_allreduce(mesh, *, axis_name: str = "pod",
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
+    from repro.distributed.sharding import shard_map
 
     def allreduce(grads, error):
         def inner(g, e):
@@ -151,10 +152,8 @@ def make_compressed_dp_allreduce(mesh, *, axis_name: str = "pod",
 
         stacked = jax.tree_util.tree_map(lambda _: P(axis_name), grads)
         rep = jax.tree_util.tree_map(lambda _: P(), grads)
-        return shard_map_compat(inner, mesh,
-                                in_specs=(stacked, stacked),
-                                out_specs=(rep, stacked),
-                                auto=auto)(grads, error)
+        return shard_map(inner, mesh, in_specs=(stacked, stacked),
+                         out_specs=(rep, stacked), auto=auto)(grads, error)
 
     return allreduce
 
@@ -166,7 +165,7 @@ def make_full_dp_allreduce(mesh, *, axis_name: str = "pod",
     The A/B baseline for benchmarks/comm_bench.py."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
+    from repro.distributed.sharding import shard_map
 
     def allreduce(grads, error):
         def inner(g, e):
@@ -176,10 +175,8 @@ def make_full_dp_allreduce(mesh, *, axis_name: str = "pod",
 
         stacked = jax.tree_util.tree_map(lambda _: P(axis_name), grads)
         rep = jax.tree_util.tree_map(lambda _: P(), grads)
-        return shard_map_compat(inner, mesh,
-                                in_specs=(stacked, stacked),
-                                out_specs=(rep, stacked),
-                                auto=auto)(grads, error)
+        return shard_map(inner, mesh, in_specs=(stacked, stacked),
+                         out_specs=(rep, stacked), auto=auto)(grads, error)
 
     return allreduce
 

@@ -149,28 +149,18 @@ def replicated(tree: Any) -> Any:
     return jax.tree_util.tree_map(lambda _: P(), tree)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False,
-                     auto: frozenset = frozenset()):
-    """shard_map across JAX versions (jax.shard_map + check_vma in newer
-    releases, jax.experimental.shard_map + check_rep in older ones).
-
-    auto: mesh axes left to the XLA partitioner instead of manually mapped
-    (tensor-parallel axes under an explicitly data-parallel collective).
-    NOTE: JAX 0.4.37 accepts the parameter but raises NotImplementedError at
-    trace time for nonempty sets — callers gate on it (ParallelPlan refuses
-    fp8 wire formats on meshes with a model axis > 1)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-        try:
-            return sm(f, auto=auto, **kw) if auto else sm(f, **kw)
-        except TypeError:   # newest JAX dropped `auto` (axis types instead)
-            return sm(f, **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=check)
-    return sm_old(f, auto=auto, **kw) if auto else sm_old(f, **kw)
+def shard_map(f, mesh, in_specs, out_specs, check: bool = False,
+              auto: frozenset = frozenset()):
+    """jax.shard_map over every mesh axis except `auto`, which stay with
+    the XLA partitioner (tensor-parallel axes under an explicitly
+    data-parallel collective)."""
+    unknown = set(auto) - set(mesh.axis_names)
+    if unknown:
+        raise ValueError(f"auto axes {sorted(unknown)} not in mesh axes "
+                         f"{mesh.axis_names}")
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check,
+                         axis_names=set(mesh.axis_names) - set(auto))
 
 
 # ---------------------------------------------------------------------------
@@ -223,20 +213,7 @@ def constrain(x, *logical_spec):
     axes do not divide the corresponding dim degrades to None. No-op outside
     a mesh context — models stay runnable on a single CPU device.
     """
-    # jax.sharding.get_abstract_mesh only exists in newer JAX; older versions
-    # install the ambient mesh via `with mesh:` and expose it through the
-    # thread-resources env. Fall back to "no mesh" (constraints become a
-    # no-op and the model stays runnable on a single device).
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is not None:
-        mesh = get_mesh()
-    else:
-        try:
-            from jax._src.mesh import thread_resources
-            pm = thread_resources.env.physical_mesh
-            mesh = None if pm.empty else pm
-        except Exception:
-            mesh = None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     if not isinstance(x, jax.core.Tracer):

@@ -22,11 +22,12 @@ collective implementations, including the wire-format knob:
       "fp8" moves the ZeRO-1 weight all-gather leg as e4m3 payloads with a
       shared per-leaf scale (1 byte/element for the frozen-format shards).
 
-Environment constraint: JAX 0.4.37's shard_map cannot leave axes to the
-auto partitioner (`auto=` raises NotImplementedError), so the fp8 wire
-formats — which need an explicit shard_map over the dp axes — are refused
-on meshes with a model axis > 1. `ParallelPlan.build` raises a clear error
-rather than failing to lower.
+Limit: the fp8 wire formats run the dp reduction inside an explicit
+shard_map that leaves the 'model' axis to the XLA partitioner. A Pallas
+kernel in that body cannot be partitioned over 'model' (XLA never
+partitions a Mosaic kernel), and the combination has no test on the XLA
+backend, so `ParallelPlan.build` refuses fp8 wire formats on meshes with a
+model axis > 1.
 """
 from __future__ import annotations
 
@@ -97,11 +98,11 @@ class ParallelPlan:
         if (dist.wire == "fp8_ef" or dist.wire_zero_gather == "fp8") \
                 and plan.tp_size > 1:
             raise NotImplementedError(
-                "fp8 wire formats need an explicit shard_map over the dp "
-                "axes, and JAX < 0.5 cannot combine that with an "
-                "auto-partitioned model axis (shard_map auto= is "
-                "NotImplemented on 0.4.37). Use a pure data-parallel mesh "
-                "or policy.dist.wire='full'.")
+                "fp8 wire formats run the dp reduction in a shard_map that "
+                "leaves the model axis to the XLA partitioner, which cannot "
+                "partition the Pallas kernels, and fp8 wire with tensor "
+                "parallelism is untested on the XLA backend. Use a pure "
+                "data-parallel mesh or policy.dist.wire='full'.")
         if dist.wire_axis is not None and dist.wire_axis not in names:
             raise ValueError(f"wire_axis {dist.wire_axis!r} not in mesh "
                              f"axes {sorted(names)}")
@@ -230,11 +231,11 @@ class ParallelPlan:
 
     # -- collectives ---------------------------------------------------------
     def shard_map(self, f, in_specs, out_specs):
-        """shard_map over the dp axes (manual); the model axis would be left
-        to the auto partitioner — refused at build() on old JAX."""
+        """shard_map over the dp axes (manual); a model axis stays with the
+        XLA partitioner (build() refuses fp8 wire formats with one)."""
         auto = frozenset({"model"}) if self.tp_size > 1 else frozenset()
-        return sharding.shard_map_compat(f, self.mesh, in_specs, out_specs,
-                                         auto=auto)
+        return sharding.shard_map(f, self.mesh, in_specs, out_specs,
+                                  auto=auto)
 
     def dp_allreduce(self, *, wire: Optional[str] = None):
         """The stacked-contract DP reduction over the wire axis:
@@ -288,22 +289,13 @@ class ParallelPlan:
     # -- error-feedback wire state -------------------------------------------
     def init_wire_state(self, params: Any) -> Any:
         """Error-feedback residual pytree: one f32 residual per wire device
-        per master leaf, stacked on a leading axis sharded P(wire_axis).
-        Lives next to ScaleState in the checkpoint."""
-        n = self.n_wire
-
-        def one(p):
-            z = jnp.zeros((n,) + tuple(np.shape(p)), jnp.float32)
-            return z
-
-        err = jax.tree_util.tree_map(one, params)
-        if jax.tree_util.tree_leaves(params) and isinstance(
-                jax.tree_util.tree_leaves(params)[0], jax.Array):
-            shardings = jax.tree_util.tree_map(
-                lambda s: NamedSharding(self.mesh, s),
-                self.wire_state_specs(err))
-            err = jax.device_put(err, shardings)
-        return err
+        per master leaf, stacked on a leading axis sharded P(wire_axis) —
+        made in place, so no device ever holds the whole stack. Lives next
+        to ScaleState in the checkpoint."""
+        stacked = NamedSharding(self.mesh, P(self.wire_axis))
+        return jax.tree_util.tree_map(
+            lambda p: jnp.zeros((self.n_wire,) + tuple(np.shape(p)),
+                                jnp.float32, device=stacked), params)
 
     def wire_state_struct(self, params_struct: Any) -> Any:
         n = self.n_wire

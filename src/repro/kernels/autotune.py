@@ -739,6 +739,8 @@ def main(argv=None):
     p.add_argument("--no-parity", action="store_true",
                    help="skip the interpret-mode winner parity gate")
     args = p.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     run_sweep(smoke=args.smoke, table_file=args.table,
               parity=not args.no_parity)
 

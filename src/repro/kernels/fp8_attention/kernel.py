@@ -55,11 +55,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 from repro.kernels.fp8_attention import ref as _r
 
 DEFAULT_BQ = 128
 DEFAULT_BKV = _r.DEFAULT_BKV   # kv-stripe rows resident in VMEM per step
+STATS_TILE = (8, 128)   # f32 observation block per (b, h, q tile): rows
+#                         0/1 the two amaxes, 2/3 the two health-count rows
 TQ = _r.TQ        # fixed dK/dV contraction granularity in query rows (not a
 #                   knob: backward results are tiling-invariant by
 #                   construction)
@@ -83,17 +84,27 @@ def _qspan(j, bq, bkv, nq, mask_mode, window):
 # forward
 # ---------------------------------------------------------------------------
 
+def _stats_row(st_ref, r):
+    return st_ref[0, 0, r:r + 1, :]
+
+
+def _set_stats_row(st_ref, r, v):
+    st_ref[0, 0, r:r + 1, :] = v
+
+
 def _fwd_body(q_ref, k_ref, v_ref, msk_ref, scal_ref, seed_ref,
-              o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr, *,
+              o_ref, st_ref, m_scr, l_scr, acc_scr, *,
               n_heads: int, bq: int, bkv: int, nk: int,
               mask_mode: str, window: int, q_len: int, s_len: int,
               fmt_s: str, fmt_p: str, rounding_s: str, rounding_p: str,
-              saturate_s: bool, saturate_p: bool,
-              hs_ref=None, hp_ref=None, chunk_ref=None):
-    # hs_ref/hp_ref: optional (1, 1, 1, 3) per-q-tile S/P precision-health
-    # count outputs ([saturated, flushed, observed] — repro.obs), bound via
-    # the _fwd_body_counts adapter. Observation-only: the stripe carries
-    # and every quantize are untouched, so counts on/off is bit-identical.
+              saturate_s: bool, saturate_p: bool, with_counts: bool,
+              chunk_ref=None):
+    # st_ref: this q tile's (8, 128) observation block, resident across the
+    # kv stripes (see STATS_TILE). The amax rows are broadcast over the
+    # lanes; with_counts adds the S/P precision-health rows
+    # ([saturated, flushed, observed] in lanes 0-2 — repro.obs).
+    # Observation-only: the stripe carries and every quantize are
+    # untouched, so counts on/off is bit-identical.
     # chunk_ref ('chunk' mode): (B, 2) int32 SMEM [start, n_valid] rows —
     # per-batch chunk coordinates, bound via the _fwd_body_chunk adapter.
     b, h, iq, j = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
@@ -106,13 +117,11 @@ def _fwd_body(q_ref, k_ref, v_ref, msk_ref, scal_ref, seed_ref,
         m_scr[...] = jnp.full_like(m_scr, -1e30)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        as_ref[...] = jnp.zeros_like(as_ref)
-        ap_ref[...] = jnp.zeros_like(ap_ref)
-        if hs_ref is not None:
-            hs_ref[...] = jnp.zeros_like(hs_ref)
-            hp_ref[...] = jnp.zeros_like(hp_ref)
+        st_ref[...] = jnp.zeros_like(st_ref)
 
-    kvmask = None if msk_ref is None else msk_ref[...]
+    # The stripe math slices the mask per LANE block; slicing the ref (not
+    # a loaded row) is what lets Mosaic broadcast each slice over the rows.
+    kvmask = None if msk_ref is None else msk_ref.at[0]
     kw = dict(seed=seed_ref[0], bh=b * n_heads + h, row0=iq * bq,
               col0=j * bkv, scal2=(scal_ref[0], scal_ref[1]),
               mask_mode=mask_mode, window=window, q_len=q_len, s_len=s_len,
@@ -121,27 +130,25 @@ def _fwd_body(q_ref, k_ref, v_ref, msk_ref, scal_ref, seed_ref,
               saturate_p=saturate_p)
     if chunk_ref is not None:
         kw["chunk"] = (chunk_ref[b, 0], chunk_ref[b, 1])
+    if with_counts:
+        kw.update(health_s=_stats_row(st_ref, 2),
+                  health_p=_stats_row(st_ref, 3))
 
     @pl.when(active)
     def _stripe():
-        if hs_ref is None:
-            m, l, acc, amax_s, amax_p, _, _ = _r.fwd_stripe_online(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], kvmask,
-                m_scr[...], l_scr[...], acc_scr[...],
-                as_ref[0, 0, 0], ap_ref[0, 0, 0], **kw)
-        else:
-            m, l, acc, amax_s, amax_p, _, _, hs, hp = _r.fwd_stripe_online(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], kvmask,
-                m_scr[...], l_scr[...], acc_scr[...],
-                as_ref[0, 0, 0], ap_ref[0, 0, 0],
-                health_s=hs_ref[0, 0, 0], health_p=hp_ref[0, 0, 0], **kw)
-            hs_ref[0, 0, 0] = hs
-            hp_ref[0, 0, 0] = hp
+        out = _r.fwd_stripe_online(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], kvmask,
+            m_scr[...], l_scr[...], acc_scr[...],
+            _stats_row(st_ref, 0), _stats_row(st_ref, 1), **kw)
+        m, l, acc, amax_s, amax_p = out[:5]
+        if with_counts:
+            _set_stats_row(st_ref, 2, out[7])
+            _set_stats_row(st_ref, 3, out[8])
         m_scr[...] = m
         l_scr[...] = l
         acc_scr[...] = acc
-        as_ref[0, 0, 0] = amax_s
-        ap_ref[0, 0, 0] = amax_p
+        _set_stats_row(st_ref, 0, amax_s)
+        _set_stats_row(st_ref, 1, amax_p)
 
     @pl.when(j == nk - 1)
     def _write():
@@ -149,6 +156,25 @@ def _fwd_body(q_ref, k_ref, v_ref, msk_ref, scal_ref, seed_ref,
         d_safe = jnp.where(l > 0, l, 1.0)
         o_ref[0, 0] = (acc_scr[...] * scal_ref[3] / d_safe
                        ).astype(jnp.bfloat16)
+
+
+def _stats_block():
+    """(1, 1, 8, 128) observation block of q tile (b, h, iq). A per-tile
+    scalar block would be (1, 1, 1), which the TPU's (8, 128) tiling
+    refuses."""
+    return pl.BlockSpec((1, 1) + STATS_TILE,
+                        lambda b, h, iq, u: (b, h, iq, 0))
+
+
+def _split_stats(st, with_counts):
+    """(B, H, nq*8, 128) stats -> (first amax, second amax) as (B, H, nq)
+    and, with counts, the two (B, H, nq, 3) health-count rows."""
+    b_, h_ = st.shape[:2]
+    st = st.reshape(b_, h_, -1, *STATS_TILE)
+    amaxes = (st[:, :, :, 0, 0], st[:, :, :, 1, 0])
+    if not with_counts:
+        return amaxes
+    return amaxes + (st[:, :, :, 2, :3], st[:, :, :, 3, :3])
 
 
 def fp8_attention_fwd_kernel(q8, k8, v8, kv_mask, seed, scal, *,
@@ -164,7 +190,7 @@ def fp8_attention_fwd_kernel(q8, k8, v8, kv_mask, seed, scal, *,
                              interpret: bool = False):
     """q8 (B,H,Qp,Dp), k8/v8 (B,Hkv,Sp,Dp) fp8 payloads (pre-padded: Qp a
     block_q multiple, Sp a block_kv multiple, Dp a LANE multiple); kv_mask
-    None or (B,Sp) int8 — (B,Sp) int32 slot positions for mask_mode='chunk',
+    None or (B,Sp) int32 validity — slot positions for mask_mode='chunk',
     padded with -1, with chunk_pos (B,2) int32 [start, n_valid] per batch;
     seed (1,) u32; scal (4,) f32 [f_s, s_s, f_p, f_o].
 
@@ -201,9 +227,11 @@ def fp8_attention_fwd_kernel(q8, k8, v8, kv_mask, seed, scal, *,
         if with_counts:
             raise ValueError("with_counts supports the training masks "
                              f"(causal/full), not {mask_mode!r}")
-        in_specs.append(pl.BlockSpec((1, bkv),
-                                     lambda b, h, iq, u: (b, u)))
-        args.append(kv_mask)
+        # (B, 1, Sp): a (1, 1, bkv) block keeps its last two dims legal
+        # for the TPU tiling at any batch size.
+        in_specs.append(pl.BlockSpec((1, 1, bkv),
+                                     lambda b, h, iq, u: (b, 0, u)))
+        args.append(kv_mask.reshape(b_, 1, sp))
         body = _fwd_body
         if mask_mode == "chunk":
             # Per-batch chunk coordinates ride whole in SMEM (scalars,
@@ -211,74 +239,53 @@ def fp8_attention_fwd_kernel(q8, k8, v8, kv_mask, seed, scal, *,
             in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
             args.append(chunk_pos)
             body = _fwd_body_chunk
-    elif with_counts:
-        body = _fwd_body_counts
     else:
         body = functools.partial(_masked_none_fwd, _fwd_body)
     in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
                  pl.BlockSpec(memory_space=pltpu.SMEM)]
     args += [scal, seed]
-    out_specs = (pl.BlockSpec((1, 1, bq, dp),
-                              lambda b, h, iq, u: (b, h, iq, 0)),
-                 pl.BlockSpec((1, 1, 1), lambda b, h, iq, u: (b, h, iq)),
-                 pl.BlockSpec((1, 1, 1), lambda b, h, iq, u: (b, h, iq)))
-    out_shape = (jax.ShapeDtypeStruct((b_, h_, qp, dp), jnp.bfloat16),
-                 jax.ShapeDtypeStruct((b_, h_, nq), jnp.float32),
-                 jax.ShapeDtypeStruct((b_, h_, nq), jnp.float32))
-    if with_counts:
-        out_specs += (pl.BlockSpec((1, 1, 1, 3),
-                                   lambda b, h, iq, u: (b, h, iq, 0)),
-                      pl.BlockSpec((1, 1, 1, 3),
-                                   lambda b, h, iq, u: (b, h, iq, 0)))
-        out_shape += (jax.ShapeDtypeStruct((b_, h_, nq, 3), jnp.float32),
-                      jax.ShapeDtypeStruct((b_, h_, nq, 3), jnp.float32))
-    return pl.pallas_call(
+    o, st = pl.pallas_call(
         functools.partial(body, n_heads=h_, bq=bq, bkv=bkv, nk=nk,
                           mask_mode=mask_mode, window=window,
                           q_len=q_len, s_len=s_len, fmt_s=fmt_s, fmt_p=fmt_p,
                           rounding_s=rounding_s, rounding_p=rounding_p,
-                          saturate_s=saturate_s, saturate_p=saturate_p),
+                          saturate_s=saturate_s, saturate_p=saturate_p,
+                          with_counts=with_counts),
         grid=grid,
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=(pl.BlockSpec((1, 1, bq, dp),
+                                lambda b, h, iq, u: (b, h, iq, 0)),
+                   _stats_block()),
+        out_shape=(jax.ShapeDtypeStruct((b_, h_, qp, dp), jnp.bfloat16),
+                   jax.ShapeDtypeStruct((b_, h_, nq * STATS_TILE[0],
+                                         STATS_TILE[1]), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, dp), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="fp8_attention_fwd",
     )(*args)
+    return (o,) + _split_stats(st, with_counts)
 
 
 def _masked_none_fwd(body, q_ref, k_ref, v_ref, scal_ref, seed_ref,
-                     o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr, **kw):
+                     o_ref, st_ref, m_scr, l_scr, acc_scr, **kw):
     """Adapter for mask-free modes: re-inserts msk_ref=None."""
     body(q_ref, k_ref, v_ref, None, scal_ref, seed_ref,
-         o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr, **kw)
+         o_ref, st_ref, m_scr, l_scr, acc_scr, **kw)
 
 
 def _fwd_body_chunk(q_ref, k_ref, v_ref, msk_ref, chunk_ref, scal_ref,
-                    seed_ref, o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr,
-                    **kw):
+                    seed_ref, o_ref, st_ref, m_scr, l_scr, acc_scr, **kw):
     """Adapter for 'chunk' mode: rebinds the positional (B, 2) SMEM chunk
     coordinates (after the slot-position mask in pallas_call order) as the
     chunk_ref keyword."""
     _fwd_body(q_ref, k_ref, v_ref, msk_ref, scal_ref, seed_ref,
-              o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr,
+              o_ref, st_ref, m_scr, l_scr, acc_scr,
               chunk_ref=chunk_ref, **kw)
-
-
-def _fwd_body_counts(q_ref, k_ref, v_ref, scal_ref, seed_ref,
-                     o_ref, as_ref, ap_ref, hs_ref, hp_ref,
-                     m_scr, l_scr, acc_scr, **kw):
-    """Mask-free forward body with the S/P health-count outputs bound
-    (training masks only — the counts path is never used for serving's
-    'kv' mode)."""
-    _fwd_body(q_ref, k_ref, v_ref, None, scal_ref, seed_ref,
-              o_ref, as_ref, ap_ref, m_scr, l_scr, acc_scr,
-              hs_ref=hs_ref, hp_ref=hp_ref, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -286,14 +293,18 @@ def _fwd_body_counts(q_ref, k_ref, v_ref, scal_ref, seed_ref,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
-                 dq_ref, m_ref, l_ref, rd_ref, adp_ref, ads_ref,
+                 dq_ref, m_ref, l_ref, rd_ref, st_ref,
                  m_scr, l_scr, rd_scr, dq_scr, *,
                  n_heads: int, bq: int, bkv: int, nk: int,
                  mask_mode: str, window: int, q_len: int, s_len: int,
                  fmt_s: str, fmt_p: str, fmt_e: str,
                  rounding_s: str, rounding_p: str, rounding_e: str,
                  saturate_s: bool, saturate_p: bool, saturate_e: bool,
-                 hdp_ref=None, hds_ref=None):
+                 with_counts: bool):
+    # st_ref: this q tile's (8, 128) observation block — rows 0/1 the dP/dS
+    # amaxes, 2/3 the dP/dS health counts when with_counts (only this
+    # kernel counts dP/dS: the dK/dV kernel replays the same quantized
+    # tiles and would double-count).
     b, h, iq, u = (pl.program_id(0), pl.program_id(1), pl.program_id(2),
                    pl.program_id(3))
     j, phase = u % nk, u // nk
@@ -310,11 +321,7 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         rd_scr[...] = jnp.zeros_like(rd_scr)
         dq_scr[...] = jnp.zeros_like(dq_scr)
-        adp_ref[...] = jnp.zeros_like(adp_ref)
-        ads_ref[...] = jnp.zeros_like(ads_ref)
-        if hdp_ref is not None:
-            hdp_ref[...] = jnp.zeros_like(hdp_ref)
-            hds_ref[...] = jnp.zeros_like(hds_ref)
+        st_ref[...] = jnp.zeros_like(st_ref)
 
     kw = dict(seed=seed_ref[0], bh=b * n_heads + h, row0=iq * bq,
               col0=j * bkv, scal2=(scal_ref[0], scal_ref[1]),
@@ -340,38 +347,29 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
     def _pass_rd():
         l = l_scr[...]
         d_safe = jnp.where(l > 0, l, 1.0)
-        if hdp_ref is not None:
-            rd, amax_dp, _, hdp = _r.bwd_stripe_rd(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
-                m_scr[...], d_safe, rd_scr[...], adp_ref[0, 0, 0],
-                health=hdp_ref[0, 0, 0], **kw, **bkw)
-            hdp_ref[0, 0, 0] = hdp
-        else:
-            rd, amax_dp, _ = _r.bwd_stripe_rd(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
-                m_scr[...], d_safe, rd_scr[...], adp_ref[0, 0, 0],
-                **kw, **bkw)
-        rd_scr[...] = rd
-        adp_ref[0, 0, 0] = amax_dp
+        hkw = dict(health=_stats_row(st_ref, 2)) if with_counts else {}
+        out = _r.bwd_stripe_rd(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
+            m_scr[...], d_safe, rd_scr[...], _stats_row(st_ref, 0),
+            **hkw, **kw, **bkw)
+        if with_counts:
+            _set_stats_row(st_ref, 2, out[3])
+        rd_scr[...] = out[0]
+        _set_stats_row(st_ref, 0, out[1])
 
     @pl.when(active & (phase == 3))
     def _pass_dq():
         l = l_scr[...]
         d_safe = jnp.where(l > 0, l, 1.0)
-        if hds_ref is not None:
-            dq_acc, amax_ds, _, hds = _r.bwd_stripe_dq(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
-                m_scr[...], d_safe, rd_scr[...], dq_scr[...],
-                ads_ref[0, 0, 0], f_ds=scal_ref[6],
-                health=hds_ref[0, 0, 0], **kw, **bkw)
-            hds_ref[0, 0, 0] = hds
-        else:
-            dq_acc, amax_ds, _ = _r.bwd_stripe_dq(
-                q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
-                m_scr[...], d_safe, rd_scr[...], dq_scr[...],
-                ads_ref[0, 0, 0], f_ds=scal_ref[6], **kw, **bkw)
-        dq_scr[...] = dq_acc
-        ads_ref[0, 0, 0] = amax_ds
+        hkw = dict(health=_stats_row(st_ref, 3)) if with_counts else {}
+        out = _r.bwd_stripe_dq(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0], None,
+            m_scr[...], d_safe, rd_scr[...], dq_scr[...],
+            _stats_row(st_ref, 1), f_ds=scal_ref[6], **hkw, **kw, **bkw)
+        if with_counts:
+            _set_stats_row(st_ref, 3, out[3])
+        dq_scr[...] = out[0]
+        _set_stats_row(st_ref, 1, out[1])
 
     @pl.when(u == 4 * nk - 1)
     def _write():
@@ -379,20 +377,6 @@ def _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
         m_ref[0, 0] = m_scr[...]
         l_ref[0, 0] = l_scr[...]
         rd_ref[0, 0] = rd_scr[...]
-
-
-def _bwd_dq_body_counts(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
-                        dq_ref, m_ref, l_ref, rd_ref, adp_ref, ads_ref,
-                        hdp_ref, hds_ref,
-                        m_scr, l_scr, rd_scr, dq_scr, **kw):
-    """Positional-ref adapter: the dP/dS health count outputs land after the
-    amax outputs in pallas_call order; rebind them as keywords. Only the dQ
-    kernel counts dP/dS — the dK/dV kernel replays the same quantized tiles
-    and would double-count."""
-    _bwd_dq_body(q_ref, k_ref, v_ref, do_ref, scal_ref, seed_ref,
-                 dq_ref, m_ref, l_ref, rd_ref, adp_ref, ads_ref,
-                 m_scr, l_scr, rd_scr, dq_scr,
-                 hdp_ref=hdp_ref, hds_ref=hds_ref, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -509,34 +493,9 @@ def fp8_attention_bwd_kernel(q8, k8, v8, do8, seed, scal, *,
         jmin, jmax = _span(iq, bq, bkv, nk, mask_mode, window)
         return (b, h // group, jnp.clip(u % nk, jmin, jmax), 0)
 
-    dq_out_specs = (
-        pl.BlockSpec((1, 1, bq, dp), lambda b, h, iq, u: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, iq, u: (b, h, iq)),
-        pl.BlockSpec((1, 1, 1), lambda b, h, iq, u: (b, h, iq)),
-    )
-    dq_out_shape = (
-        jax.ShapeDtypeStruct((b_, h_, qp, dp), jnp.float32),
-        jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
-        jax.ShapeDtypeStruct((b_, h_, nq), jnp.float32),
-        jax.ShapeDtypeStruct((b_, h_, nq), jnp.float32),
-    )
-    dq_body = _bwd_dq_body
-    if with_counts:
-        dq_body = _bwd_dq_body_counts
-        dq_out_specs += (pl.BlockSpec((1, 1, 1, 3),
-                                      lambda b, h, iq, u: (b, h, iq, 0)),
-                         pl.BlockSpec((1, 1, 1, 3),
-                                      lambda b, h, iq, u: (b, h, iq, 0)))
-        dq_out_shape += (jax.ShapeDtypeStruct((b_, h_, nq, 3), jnp.float32),
-                         jax.ShapeDtypeStruct((b_, h_, nq, 3), jnp.float32))
     dq_outs = pl.pallas_call(
-        functools.partial(dq_body, n_heads=h_, bq=bq, bkv=bkv, nk=nk,
-                          **fmt_kw),
+        functools.partial(_bwd_dq_body, n_heads=h_, bq=bq, bkv=bkv, nk=nk,
+                          with_counts=with_counts, **fmt_kw),
         grid=(b_, h_, nq, 4 * nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, dp), lambda b, h, iq, u: (b, h, iq, 0)),
@@ -546,21 +505,33 @@ def fp8_attention_bwd_kernel(q8, k8, v8, do8, seed, scal, *,
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=dq_out_specs,
-        out_shape=dq_out_shape,
+        out_specs=(
+            pl.BlockSpec((1, 1, bq, dp), lambda b, h, iq, u: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, iq, u: (b, h, iq, 0)),
+            _stats_block(),
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((b_, h_, qp, dp), jnp.float32),
+            jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b_, h_, qp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b_, h_, nq * STATS_TILE[0],
+                                  STATS_TILE[1]), jnp.float32),
+        ),
         scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, dp), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="fp8_attention_bwd_dq",
     )(q8, k8, v8, do8, scal, seed)
-    if with_counts:
-        dq, m, l, rd, amax_dp, amax_ds, hdp, hds = dq_outs
-    else:
-        dq, m, l, rd, amax_dp, amax_ds = dq_outs
+    dq, m, l, rd, st = dq_outs
+    stats = _split_stats(st, with_counts)
 
     def q_index(b, hkv_, j, t):
         # Shared by the q/do blocks AND the m/l/rd statistics blocks —
@@ -596,10 +567,9 @@ def fp8_attention_bwd_kernel(q8, k8, v8, do8, seed, scal, *,
             jax.ShapeDtypeStruct((b_, hkv, sp, dp), jnp.float32),
         ),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="fp8_attention_bwd_dkv",
     )(q8, do8, k8, v8, m, l, rd, scal, seed)
-    if with_counts:
-        return dq, dk, dv, amax_dp, amax_ds, hdp, hds
-    return dq, dk, dv, amax_dp, amax_ds
+    return (dq, dk, dv) + stats

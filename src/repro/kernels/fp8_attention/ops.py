@@ -88,7 +88,8 @@ def fp8_attention_fwd(q8, k8, v8, seed, scal, *, mask_mode: str = "causal",
     mask = None
     cpos = None
     if mask_mode == "kv":
-        mask = _r._pad_to(kv_mask.astype(jnp.int8), 1, bkv)
+        # int32: a one-row int8 block is below the TPU's int8 tiling.
+        mask = _r._pad_to(kv_mask.astype(jnp.int32), 1, bkv)
     elif mask_mode == "chunk":
         # Slot positions pad with -1: 0 is a VALID position, so the usual
         # zero padding would alias slot 0 into every padded lane.

@@ -235,20 +235,23 @@ def _zeros_like_fp8(x):
 
 
 def _health_counts(q8t, obs, fmt_name: str):
-    """(3,) f32 [saturated, flushed, observed] counts of one quantized tile
-    over its observed region — the precision-health counters (repro.obs)
-    accumulated next to the amax observations, from values already in
-    VMEM/registers. Saturated: |q| at/above the format ceiling, inf/nan
-    included (non-saturating error tensors keep inf). Flushed: |q| below
-    min_normal (exact zeros + subnormals)."""
+    """(1, LANE) f32 row [saturated, flushed, observed, 0, ...] of counts of
+    one quantized tile over its observed region — the precision-health
+    counters (repro.obs) accumulated next to the amax observations, from
+    values already in VMEM/registers. A lane row (not a (3,) vector) is
+    what a kernel's (8, 128) stats block stores. Saturated: |q| at/above
+    the format ceiling, inf/nan included (non-saturating error tensors keep
+    inf). Flushed: |q| below min_normal (exact zeros + subnormals)."""
     fmt = get_format(fmt_name)
     qf = q8t.astype(jnp.float32)
     a = jnp.abs(qf)
     sat = (a >= jnp.float32(fmt.max_normal)) | ~jnp.isfinite(qf)
     flush = a < jnp.float32(fmt.min_normal)
-    return jnp.stack([jnp.sum(jnp.where(obs & sat, 1.0, 0.0)),
-                      jnp.sum(jnp.where(obs & flush, 1.0, 0.0)),
-                      jnp.sum(jnp.where(obs, 1.0, 0.0))])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+    row = jnp.where(lane == 2, jnp.sum(jnp.where(obs, 1.0, 0.0)), 0.0)
+    row = jnp.where(lane == 1, jnp.sum(jnp.where(obs & flush, 1.0, 0.0)),
+                    row)
+    return jnp.where(lane == 0, jnp.sum(jnp.where(obs & sat, 1.0, 0.0)), row)
 
 
 def _sblocks(q8, k8s, kvmask_s, *, seed, bh, row0, col0, scal2,
@@ -293,7 +296,7 @@ def fwd_stripe_m(q8, k8s, kvmask_s, m, amax_s, *, payload=False,
     recompute (and the retained two-pass baseline `fwd_q_tile_two_pass`)
     use this; the forward kernel itself runs the one-pass
     `fwd_stripe_online`. Returns (m, amax_s, s8_tiles) — tiles only when
-    payload=True (oracle use). With a (3,) `health` accumulator,
+    payload=True (oracle use). With a (1, LANE) `health` accumulator,
     additionally returns it advanced by this stripe's S precision-health
     counts (4-tuple; the observation-only extra output never perturbs the
     carries — counters on/off is bit-identical)."""
@@ -339,7 +342,7 @@ def fwd_stripe_online(q8, k8s, v8s, kvmask_s, m, l, acc, amax_s, amax_p, *,
     construction); normalization by the final l happens once at write-out.
 
     Returns (m, l, acc, amax_s, amax_p, s8_tiles, p8_tiles) — tile lists
-    only when payload=True (oracle use). With (3,) `health_s`/`health_p`
+    only when payload=True (oracle use). With (1, LANE) `health_s`/`health_p`
     accumulators, additionally returns both advanced by this stripe's S/P
     precision-health counts (observation-only: carries are untouched, so
     counters on/off is bit-identical)."""
@@ -382,7 +385,7 @@ def fwd_stripe_pv(q8, k8s, v8s, kvmask_s, m, d_safe, acc, amax_p, *,
     quantized probs + P amax + PV accumulation. Retained as the two-pass
     baseline for the one-pass A/B bench and equivalence tests — the
     forward kernel runs `fwd_stripe_online`. Returns (acc, amax_p,
-    p8_tiles) — plus the advanced (3,) P health counts when a `health`
+    p8_tiles) — plus the advanced (1, LANE) P health counts when a `health`
     accumulator is given."""
     tiles = []
     bq = q8.shape[0]
@@ -435,7 +438,7 @@ def bwd_stripe_rd(q8, k8s, v8s, do8, kvmask_s, m, d_safe, rd, amax_dp, *,
                   payload=False, health=None, **kw):
     """Backward pass A over one stripe: the softmax-VJP row reduction
     rowsum(P * dP) carry + the dP observation. Returns
-    (rd, amax_dp, dp8_tiles) — plus the advanced (3,) dP health counts
+    (rd, amax_dp, dp8_tiles) — plus the advanced (1, LANE) dP health counts
     when a `health` accumulator is given."""
     tiles = []
     for jj, p8, p_d, dp8, dp_d, cols, obs, valid in _pdp_blocks(
@@ -465,7 +468,7 @@ def bwd_stripe_dq(q8, k8s, v8s, do8, kvmask_s, m, d_safe, rd,
                   **kw):
     """Backward pass B (query side) over one stripe: dS quantization, the
     dQ accumulation, and the dS observation. Returns
-    (dq_acc, amax_ds, ds8_tiles) — plus the advanced (3,) dS health counts
+    (dq_acc, amax_ds, ds8_tiles) — plus the advanced (1, LANE) dS health counts
     when a `health` accumulator is given."""
     bq = q8.shape[0]
     rows = kw["row0"] + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_BM = 256
 DEFAULT_BK = 512
@@ -63,6 +62,6 @@ def fp8_matmul_kernel(a, b, *, bm=DEFAULT_BM, bk=DEFAULT_BK, bn=DEFAULT_BN,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, b)

@@ -41,11 +41,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fp8_formats import get_format
 from repro.core.quantize import quantize_rne, sr_fp8_via_f16
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_BM = 256
 DEFAULT_BK = 512
 DEFAULT_BN = 256
+STATS_TILE = (8, 128)   # one f32 (sublane, lane) tile of stats per grid cell
 
 DIMS = ("nn", "nt", "tn")
 
@@ -87,79 +87,41 @@ def _amax_mask(bm: int, bn: int, m: int, n: int):
     return (rows < m) & (cols < n)
 
 
-def _body(a_ref, b_ref, rand_ref, scale_ref, o_ref, acc_ref, *,
-          dims: str, fmt_name: str, rounding: str, saturate: bool, n_k: int):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+def _body(a_ref, b_ref, rand_ref, scale_ref, o_ref, *refs,
+          dims: str, fmt_name: str, rounding: str, saturate: bool, n_k: int,
+          m: int, n: int, with_amax: bool, with_counts: bool):
+    """k-sweep accumulation plus the Q-node epilogue. With `with_amax`, the
+    epilogue also writes the tile's observation into its (8, 128) stats
+    block, for delayed scaling: the observed amax of the quantized tile,
+    computed from the fp8 values while they are STILL IN VMEM, so the
+    observation costs no extra pass over HBM (a separate amax op re-reads
+    the whole output). The amax is in grid units (max |q| of the quantized
+    values, no scale multiply) and masked to the logical (m, n) region,
+    exactly matching the bit-pattern reduction core.quantize.fp8_amax_bits
+    performs on a materialized payload.
 
-    acc_ref[...] += _tile_dot(a_ref[...], b_ref[...], dims)
-
-    @pl.when(pl.program_id(2) == n_k - 1)
-    def _epilogue():
-        inv = 1.0 / scale_ref[0]
-        o_ref[...] = _quantize_tile(acc_ref[...], rand_ref[...], inv,
-                                    fmt_name=fmt_name, rounding=rounding,
-                                    saturate=saturate)
-
-
-def _body_amax(a_ref, b_ref, rand_ref, scale_ref, o_ref, amax_ref, acc_ref, *,
-               dims: str, fmt_name: str, rounding: str, saturate: bool,
-               n_k: int, m: int, n: int):
-    """_body plus a per-tile amax epilogue output for delayed scaling: the
-    observed amax of the quantized tile is computed from the fp8 values
-    while they are STILL IN VMEM — the observation costs no extra pass over
-    HBM (the alternative, a separate amax op, re-reads the whole output).
-    The amax is in grid units (max |q| of the quantized values, no scale
-    multiply) and is masked to the logical (m, n) region, exactly matching
-    the bit-pattern reduction core.quantize.fp8_amax_bits performs on a
-    materialized payload."""
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += _tile_dot(a_ref[...], b_ref[...], dims)
-
-    # Computed at body top level: jax 0.4.37's interpret mode does not
-    # substitute program_id inside pl.when sub-jaxprs (value uses only;
-    # conditions are fine) — the epilogue closes over the mask instead.
-    bm, bn = acc_ref.shape
-    mask = _amax_mask(bm, bn, m, n)
-
-    @pl.when(pl.program_id(2) == n_k - 1)
-    def _epilogue():
-        inv = 1.0 / scale_ref[0]
-        q = _quantize_tile(acc_ref[...], rand_ref[...], inv,
-                           fmt_name=fmt_name, rounding=rounding,
-                           saturate=saturate)
-        o_ref[...] = q
-        mag = jnp.where(mask, jnp.abs(q.astype(jnp.float32)), 0.0)
-        amax_ref[0, 0] = jnp.max(mag)
-
-
-def _body_amax_counts(a_ref, b_ref, rand_ref, scale_ref, o_ref, amax_ref,
-                      sat_ref, flush_ref, acc_ref, *,
-                      dims: str, fmt_name: str, rounding: str, saturate: bool,
-                      n_k: int, m: int, n: int):
-    """_body_amax plus per-tile precision-health counts (repro.obs): how many
+    `with_counts` adds the precision-health counts (repro.obs): how many
     quantized values landed at/above the format ceiling (saturated — inf/nan
-    from non-saturating error outputs included) and how many below min_normal
-    (flushed: exact zeros + subnormals). Counted from the fp8 tile while it
-    is STILL IN VMEM, in the same epilogue as the amax — the counters cost no
-    extra pass over HBM — and masked to the logical (m, n) region like the
-    amax. The quantize computation is untouched: counts on/off is
-    bit-identical output (the repro.obs parity law)."""
+    from non-saturating error outputs included) and how many below
+    min_normal (flushed: exact zeros + subnormals), masked like the amax.
+    The quantize computation is untouched: counts on/off is bit-identical
+    output (the repro.obs parity law).
+
+    Stats block rows (each broadcast over the 128 lanes): 0 amax, 1
+    saturated count, 2 flushed count, the rest zero. A per-cell scalar
+    block would be (1, 1), which the TPU's (8, 128) tiling refuses."""
+    stats_ref = refs[0] if with_amax else None
+    acc_ref = refs[-1]
+
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += _tile_dot(a_ref[...], b_ref[...], dims)
 
-    bm, bn = acc_ref.shape
-    mask = _amax_mask(bm, bn, m, n)
-    fmt = get_format(fmt_name)
-    hi = jnp.float32(fmt.max_normal)
-    lo = jnp.float32(fmt.min_normal)
+    # Built at body top level: interpret mode does not substitute
+    # program_id inside pl.when sub-jaxprs, so the epilogue closes over it.
+    mask = _amax_mask(*acc_ref.shape, m, n) if with_amax else None
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
@@ -168,13 +130,22 @@ def _body_amax_counts(a_ref, b_ref, rand_ref, scale_ref, o_ref, amax_ref,
                            fmt_name=fmt_name, rounding=rounding,
                            saturate=saturate)
         o_ref[...] = q
+        if stats_ref is None:
+            return
         qf = q.astype(jnp.float32)
-        mag = jnp.where(mask, jnp.abs(qf), 0.0)
-        amax_ref[0, 0] = jnp.max(mag)
-        sat = (jnp.abs(qf) >= hi) | ~jnp.isfinite(qf)
-        flush = jnp.abs(qf) < lo
-        sat_ref[0, 0] = jnp.sum(jnp.where(mask & sat, 1.0, 0.0))
-        flush_ref[0, 0] = jnp.sum(jnp.where(mask & flush, 1.0, 0.0))
+        row = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape, 0)
+        stats = jnp.where(row == 0,
+                          jnp.max(jnp.where(mask, jnp.abs(qf), 0.0)), 0.0)
+        if with_counts:
+            fmt = get_format(fmt_name)
+            sat = (jnp.abs(qf) >= jnp.float32(fmt.max_normal)) \
+                | ~jnp.isfinite(qf)
+            flush = jnp.abs(qf) < jnp.float32(fmt.min_normal)
+            stats = jnp.where(
+                row == 1, jnp.sum(jnp.where(mask & sat, 1.0, 0.0)), stats)
+            stats = jnp.where(
+                row == 2, jnp.sum(jnp.where(mask & flush, 1.0, 0.0)), stats)
+        stats_ref[...] = stats
 
 
 def _block_specs(dims: str, bm: int, bk: int, bn: int):
@@ -238,42 +209,34 @@ def fused_quant_matmul_kernel(a, b, rand8, scale, *,
         in_specs=in_specs,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
-    out_dtype = get_format(out_format).dtype
     if with_counts and not with_amax:
         raise ValueError("with_counts requires with_amax")
-    if not with_amax:
-        return pl.pallas_call(
-            functools.partial(_body, dims=dims, fmt_name=out_format,
-                              rounding=rounding, saturate=saturate,
-                              n_k=grid[2]),
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-            **common,
-        )(a, b, rand8, scale)
-    if not with_counts:
-        return pl.pallas_call(
-            functools.partial(_body_amax, dims=dims, fmt_name=out_format,
-                              rounding=rounding, saturate=saturate,
-                              n_k=grid[2], m=lm, n=ln),
-            out_specs=(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-                       pl.BlockSpec((1, 1), lambda i, j, kk: (i, j))),
-            out_shape=(jax.ShapeDtypeStruct((m, n), out_dtype),
-                       jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32)),
-            **common,
-        )(a, b, rand8, scale)
-    tile_f32 = jax.ShapeDtypeStruct((grid[0], grid[1]), jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_body_amax_counts, dims=dims, fmt_name=out_format,
-                          rounding=rounding, saturate=saturate,
-                          n_k=grid[2], m=lm, n=ln),
-        out_specs=(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j, kk: (i, j))),
-        out_shape=(jax.ShapeDtypeStruct((m, n), out_dtype),
-                   tile_f32, tile_f32, tile_f32),
+    out_block = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
+    out_shape = jax.ShapeDtypeStruct((m, n), get_format(out_format).dtype)
+    if with_amax:
+        tr, tc = STATS_TILE
+        out_block = (out_block,
+                     pl.BlockSpec(STATS_TILE, lambda i, j, kk: (i, j)))
+        out_shape = (out_shape, jax.ShapeDtypeStruct(
+            (grid[0] * tr, grid[1] * tc), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_body, dims=dims, fmt_name=out_format,
+                          rounding=rounding, saturate=saturate, n_k=grid[2],
+                          m=lm, n=ln, with_amax=with_amax,
+                          with_counts=with_counts),
+        out_specs=out_block,
+        out_shape=out_shape,
+        name=f"fused_quant_matmul_{dims}",
         **common,
     )(a, b, rand8, scale)
+    if not with_amax:
+        return out
+    q, stats = out
+    # Lane 0 of each stats row holds the per-cell value: (grid_m, grid_n).
+    stats = stats.reshape(grid[0], tr, grid[1], tc)[:, :, :, 0]
+    if not with_counts:
+        return q, stats[:, 0]
+    return q, stats[:, 0], stats[:, 1], stats[:, 2]

@@ -27,7 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fp8_formats import get_format
 from repro.core.quantize import sr_fp8_via_f16
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 # Block shape: 8x128 VPU lanes; 512x1024 f32 = 2 MiB in + 0.5 MiB out per
 # block — comfortably inside a 16 MiB VMEM with double buffering.
@@ -73,7 +72,7 @@ def sr_quantize_kernel(x, rand8, scale, *, block=DEFAULT_BLOCK,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), get_format(fmt).dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x, rand8, scale)
 
@@ -94,6 +93,6 @@ def sr_quantize_kernel_onchip(x, seed, scale, *, block=DEFAULT_BLOCK,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), get_format(fmt).dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(seed, x, scale)

@@ -27,8 +27,7 @@ from pathlib import Path
 
 import jax
 
-from repro.launch.mesh import (enter_mesh, jit_shardings,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (GRID_ARCHS, SHAPES, build_cell,
                                cell_supported, parse_overrides)
 
@@ -100,14 +99,14 @@ def run_cell(arch: str, shape: str, mesh_kind: str, *,
         rec["unroll"] = True
     overrides = overrides or None
     try:
-        with enter_mesh(mesh):
+        with jax.set_mesh(mesh):
             cell = build_cell(arch, shape, mesh, unroll_layers=unroll,
                               overrides=overrides)
             rec["meta"] = cell["meta"]
             lowered = jax.jit(
                 cell["fn"],
-                in_shardings=jit_shardings(mesh, cell["in_shardings"]),
-                out_shardings=jit_shardings(mesh, cell["out_shardings"]),
+                in_shardings=cell["in_shardings"],
+                out_shardings=cell["out_shardings"],
                 donate_argnums=cell.get("donate_argnums", ()),
             ).lower(*cell["args"])
             rec["lower_s"] = round(time.time() - t0, 2)
@@ -172,6 +171,8 @@ def main():
                          "policy.quant.scaling=delayed")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     overrides = parse_overrides(args.overrides)
 

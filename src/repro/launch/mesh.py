@@ -8,6 +8,7 @@ see distributed/strategy.py).
 
 These are FUNCTIONS, not module constants: importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS before first init).
+Install a mesh as the ambient one with `jax.set_mesh(mesh)`.
 """
 from __future__ import annotations
 
@@ -25,36 +26,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # axis_types / AxisType only exist in newer JAX; older versions default
-    # every axis to auto sharding, which is exactly what we want anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def enter_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh, across JAX
-    versions: jax.set_mesh where present, else the legacy `with mesh:`
-    (Mesh is itself a context manager in older JAX)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def jit_shardings(mesh, tree):
-    """PartitionSpec tree -> what jax.jit's in/out_shardings accepts on this
-    JAX version: newer JAX takes bare PartitionSpecs (resolved against the
-    ambient mesh); older JAX requires explicit NamedSharding objects."""
-    if getattr(jax, "set_mesh", None) is not None:
-        return tree
-    from jax.sharding import NamedSharding, PartitionSpec
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec)
-        else s,
-        tree, is_leaf=lambda x: isinstance(x, PartitionSpec))
+    """A mesh whose axes are all left to the XLA partitioner (Auto)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # Axis bookkeeping (dp axes present, per-axis sizes, wire-axis choice) lives

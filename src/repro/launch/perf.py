@@ -30,8 +30,7 @@ from pathlib import Path
 import jax
 
 from repro.launch.dryrun import parse_collectives
-from repro.launch.mesh import (enter_mesh, jit_shardings,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell, parse_overrides
 from repro.roofline.analysis import analyze_record
 
@@ -62,14 +61,14 @@ def run_variant(arch: str, shape: str, variant: str, overrides: dict, *,
                n_devices=mesh.devices.size, status="pending")
     t0 = time.time()
     try:
-        with enter_mesh(mesh):
+        with jax.set_mesh(mesh):
             cell = build_cell(arch, shape, mesh, unroll_layers=unroll,
                               overrides=overrides)
             rec["meta"] = cell["meta"]
             compiled = jax.jit(
                 cell["fn"],
-                in_shardings=jit_shardings(mesh, cell["in_shardings"]),
-                out_shardings=jit_shardings(mesh, cell["out_shardings"]),
+                in_shardings=cell["in_shardings"],
+                out_shardings=cell["out_shardings"],
                 donate_argnums=cell.get("donate_argnums", ()),
             ).lower(*cell["args"]).compile()
             ma = compiled.memory_analysis()
@@ -119,6 +118,8 @@ def main():
     ap.add_argument("--set", nargs="*", default=[],
                     help="key=value ModelConfig/policy overrides")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     overrides = parse_overrides(args.set)
     run_variant(args.arch, args.shape, args.variant, overrides,
                 unroll=args.unroll)

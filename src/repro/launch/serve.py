@@ -6,12 +6,64 @@ fixed-slot engine (the differential-parity oracle).
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --smoke
   PYTHONPATH=src python -m repro.launch.serve --smoke --temperature 0.8 \\
       --top-p 0.95 --page-size 8 --n-pages 32
+  # Fused FP8 serving (Pallas kernels, scales calibrated then frozen):
+  PYTHONPATH=src python -m repro.launch.serve --smoke \\
+      --set policy.quant.backend=pallas --set policy.quant.recipe=hybrid \\
+      --set policy.quant.scaling=delayed
+
+`--set key=value` takes the same overrides as the dry-run. Under
+`policy.quant.scaling=delayed` the engine serves from scales calibrated on
+a few synthetic batches and frozen (`calibrated_scales`). `make_engine`
+and `serve_requests` are the launcher's steps as functions; `chip_smoke.py`
+calls them too.
 """
 import argparse
 import json
+from typing import Dict, List, Sequence
 
 import jax
 import numpy as np
+
+
+def calibrated_scales(cfg, params, batches):
+    """(frozen scales, their formats) from forward passes over `batches`
+    ({"tokens": (B, S) int32} dicts) — None, None unless the policy uses
+    delayed scaling."""
+    if cfg.policy.quant.scaling != "delayed":
+        return None, None
+    from repro.scaling import calibrate, freeze_with_formats
+    ds, state = calibrate(params, cfg, batches)
+    return freeze_with_formats(ds, state, cfg)
+
+
+def make_engine(cfg, params, serve_cfg, *, calib_batches=(), legacy=False):
+    """The paged engine (or, with `legacy`, the fixed-slot one), serving
+    from frozen calibrated scales when the policy asks for delayed
+    scaling."""
+    from repro.serve import PagedServeEngine, ServeEngine
+    frozen, formats = calibrated_scales(cfg, params, calib_batches)
+    engine = ServeEngine if legacy else PagedServeEngine
+    return engine(cfg, params, serve_cfg, frozen_scales=frozen,
+                  frozen_formats=formats)
+
+
+def serve_requests(engine, prompts: Sequence[np.ndarray], *,
+                   max_new_tokens: int, on_tokens=None
+                   ) -> Dict[int, List[int]]:
+    """Admit `prompts` as slots free up and step the engine until all are
+    served. Returns {prompt index: generated tokens}; `on_tokens(index,
+    tokens)` sees each request as it finishes."""
+    pending = list(enumerate(prompts))
+    index_of, out = {}, {}
+    while pending or any(s is not None for s in engine.slots):
+        while pending and engine.free_slots():
+            i, p = pending.pop(0)
+            index_of[engine.add_request(p, max_new_tokens=max_new_tokens)] = i
+        for uid, toks in engine.step().items():
+            out[index_of[uid]] = toks
+            if on_tokens:
+                on_tokens(index_of[uid], toks)
+    return out
 
 
 def main():
@@ -42,17 +94,27 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--stats", action="store_true",
                     help="print the engine stats() snapshot at the end")
+    ap.add_argument("--set", action="append", default=[],
+                    metavar="KEY=VALUE",
+                    help="config override, as in the dry-run (e.g. "
+                         "policy.quant.backend=pallas)")
     args = ap.parse_args()
 
     import dataclasses
 
     from repro.checkpoint import Checkpointer
+    from repro.launch.cache import use_compile_cache
+    from repro.launch.specs import apply_overrides, parse_overrides
     from repro.models.registry import build_config
     from repro.models.transformer import init_lm
-    from repro.serve import (PagedServeConfig, PagedServeEngine, ServeConfig,
-                             ServeEngine)
+    from repro.serve import PagedServeConfig, ServeConfig
 
+    use_compile_cache()
     cfg = build_config(args.arch, smoke=args.smoke)
+    cfg, _, _, serve_kw = apply_overrides(cfg, parse_overrides(args.set))
+    if serve_kw:
+        raise ValueError("serve.* overrides configure dry-run cells; use "
+                         "the --page-size/--n-pages/--chunk-size flags")
     if args.fp8_kv:
         cfg = cfg.replace(policy=dataclasses.replace(
             cfg.policy, kv_cache_format="e5m2"))
@@ -65,33 +127,25 @@ def main():
             print(f"restored params at step {step}")
 
     if args.legacy:
-        eng = ServeEngine(cfg, params, ServeConfig(
-            max_batch=args.max_batch, max_len=args.max_len,
-            temperature=args.temperature, seed=args.seed))
+        serve_cfg = ServeConfig(max_batch=args.max_batch,
+                                max_len=args.max_len,
+                                temperature=args.temperature, seed=args.seed)
     else:
-        eng = PagedServeEngine(cfg, params, PagedServeConfig(
+        serve_cfg = PagedServeConfig(
             max_batch=args.max_batch, max_len=args.max_len,
             n_pages=args.n_pages, page_size=args.page_size,
             chunk_size=args.chunk_size, temperature=args.temperature,
             top_k=args.top_k, top_p=args.top_p, seed=args.seed,
-            prefix_cache=not args.no_prefix_cache))
+            prefix_cache=not args.no_prefix_cache)
     rng = np.random.default_rng(0)
-    pending = [rng.integers(0, cfg.vocab_size, size=rng.integers(4, 12))
+    calib = [{"tokens": rng.integers(0, cfg.vocab_size, (args.max_batch, 16),
+                                     dtype=np.int32)} for _ in range(2)]
+    eng = make_engine(cfg, params, serve_cfg, calib_batches=calib,
+                      legacy=args.legacy)
+    prompts = [rng.integers(0, cfg.vocab_size, size=rng.integers(4, 12))
                for _ in range(args.n_requests)]
-    uid_to_req = {}
-    i = 0
-
-    def active():
-        return any(s is not None for s in eng.slots)
-
-    while pending or active():
-        while pending and eng.free_slots():
-            p = pending.pop(0)
-            uid = eng.add_request(p, max_new_tokens=16)
-            uid_to_req[uid] = i
-            i += 1
-        for uid, toks in eng.step().items():
-            print(f"request {uid_to_req[uid]}: generated {toks}")
+    serve_requests(eng, prompts, max_new_tokens=16,
+                   on_tokens=lambda i, t: print(f"request {i}: generated {t}"))
     print("all requests served")
     if args.stats:
         print(json.dumps(eng.stats(), indent=1))

@@ -125,7 +125,7 @@ def _cfg_for_cell(arch: str, shape: str) -> ModelConfig:
     return cfg.replace(max_seq_len=max(cfg.max_seq_len, seq))
 
 
-def _apply_overrides(cfg: ModelConfig, overrides: Optional[Dict[str, Any]]
+def apply_overrides(cfg: ModelConfig, overrides: Optional[Dict[str, Any]]
                      ) -> Tuple[ModelConfig, Any, Any, Dict[str, Any]]:
     """Apply dotted-key cell overrides to a ModelConfig.
 
@@ -175,7 +175,7 @@ def cell_config(arch: str, shape: str, *,
     building anything.  Used by `repro.analysis.precision_lint` to
     classify jaxpr findings against the cell's actual knobs."""
     cfg = _cfg_for_cell(arch, shape)
-    return _apply_overrides(cfg, overrides)[0]
+    return apply_overrides(cfg, overrides)[0]
 
 
 def build_cell(arch: str, shape: str, mesh, *,
@@ -201,7 +201,7 @@ def build_cell(arch: str, shape: str, mesh, *,
     info = SHAPES[shape]
     seq, batch, mode = info["seq"], info["batch"], info["mode"]
     cfg = _cfg_for_cell(arch, shape)
-    cfg, force_nmb, force_sp, serve_kw = _apply_overrides(cfg, overrides)
+    cfg, force_nmb, force_sp, serve_kw = apply_overrides(cfg, overrides)
     if unroll_layers:
         cfg = cfg.replace(scan_layers=False)
     # The plan owns every sharding decision from here on: dp/zero1/tp axes,

@@ -71,6 +71,8 @@ def main():
     ap.add_argument("--out", default="experiments/lint/findings.json")
     ap.add_argument("--md", default="experiments/lint/report.md")
     args = ap.parse_args()
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     if args.all:
         archs = list(GRID_ARCHS) + [a for a in PAPER_ARCHS

@@ -25,6 +25,8 @@ def main(argv=None) -> int:
     ap.add_argument("out_dir", nargs="?", default="experiments/obs")
     ap.add_argument("--steps", type=int, default=12)
     args = ap.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     out = Path(args.out_dir)
     out.mkdir(parents=True, exist_ok=True)
 

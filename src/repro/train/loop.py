@@ -25,6 +25,7 @@ Production behaviors for the 1000-node regime, exercised at CPU scale:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import signal
 import time
@@ -32,9 +33,12 @@ from typing import Any, Callable, Dict, Iterator, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import Checkpointer
 from repro.core.master_weights import MixedPrecisionOptimizer
+from repro.distributed.sharding import replicated
 from repro.models.config import ModelConfig
 from repro.models.transformer import init_lm
 from repro.obs.health import HealthConfig, HealthMonitor
@@ -50,7 +54,7 @@ Array = jax.Array
 class LoopConfig:
     total_steps: int = 100
     checkpoint_every: int = 50
-    checkpoint_dir: str = "/tmp/repro_ckpt"
+    checkpoint_dir: Optional[str] = "/tmp/repro_ckpt"   # None: no checkpoints
     keep_last_k: int = 3
     log_every: int = 10
     metrics_path: Optional[str] = None
@@ -96,12 +100,17 @@ class TrainLoop:
         self.scaling = scaling
         self.plan = plan
         self.wire = plan is not None and plan.compresses
-        self.ckpt = Checkpointer(loop.checkpoint_dir,
-                                 keep_last_k=loop.keep_last_k)
+        self.ckpt = None if loop.checkpoint_dir is None else Checkpointer(
+            loop.checkpoint_dir, keep_last_k=loop.keep_last_k)
         self._stop = False
+        # The carried state (train state, then ScaleState and the wire
+        # residuals when present) is donated: the step's outputs replace
+        # it, so a step holds one copy of the state, not two.
+        n_carried = 1 + (scaling is not None) + self.wire
         self._step_fn = jax.jit(make_train_step(
             cfg, optimizer, n_microbatches=loop.n_microbatches,
-            scaling=scaling, amax_sync=amax_sync, plan=plan))
+            scaling=scaling, amax_sync=amax_sync, plan=plan),
+            donate_argnums=tuple(range(n_carried)))
         # Timing probe for the wire collective: the step is ONE jitted
         # program, so the reduction cannot be timed from the host inside
         # it — instead a standalone jit of the same collective runs on the
@@ -158,28 +167,54 @@ class TrainLoop:
         return (tree["train"], tree.get("amax_scales"),
                 tree.get("wire_error"))
 
+    def step_key(self, step: int) -> Array:
+        """The quantization (SR) key of `step`."""
+        return jax.random.fold_in(jax.random.PRNGKey(self.seed + 17), step)
+
+    def _shard(self, tree, specs):
+        """Place `tree` on the plan's mesh per a PartitionSpec tree."""
+        return jax.device_put(tree, jax.tree_util.tree_map(
+            lambda s: NamedSharding(self.plan.mesh, s), specs,
+            is_leaf=lambda x: isinstance(x, P)))
+
+    def place_batch(self, batch):
+        """`batch` as the step receives it: split over the plan's dp axes
+        (unchanged without a plan)."""
+        if self.plan is None:
+            return batch
+        return self._shard(batch, self.plan.batch_specs(batch))
+
     def run(self) -> Dict[str, Any]:
-        with MetricsLogger(self.loop.metrics_path, meta=self._logger_meta(),
-                           window=self.loop.metrics_window) as logger:
+        # Under a plan the step runs with its mesh installed, so the model's
+        # activation constraints apply, and every operand is placed by the
+        # plan: state per its ZeRO-1 layout, each batch over the dp axes.
+        mesh = jax.set_mesh(self.plan.mesh) if self.plan is not None \
+            else contextlib.nullcontext()
+        with mesh, MetricsLogger(self.loop.metrics_path,
+                                 meta=self._logger_meta(),
+                                 window=self.loop.metrics_window) as logger:
             try:
                 return self._run(logger)
             finally:
                 self.tracer.export()
 
     def _run(self, logger: MetricsLogger) -> Dict[str, Any]:
-        params = init_lm(jax.random.PRNGKey(self.seed), self.cfg)
-        state = self.optimizer.init(params)
+        state = self.optimizer.init(
+            init_lm(jax.random.PRNGKey(self.seed), self.cfg))
+        if self.plan is not None:
+            # Shard before anything else is allocated: the whole state
+            # was just made on one device.
+            state = self._shard(state, self.plan.train_state_specs(state))
         scale_state = self.scaling.init() if self.scaling else None
         err = self.plan.init_wire_state(state.master) if self.wire else None
         if self.wire:
             self._comm = {f"comm/{k}": v for k, v in
                           self.plan.wire_bytes(state.master).items()
                           if isinstance(v, (int, float))}
-        del params
         start_step = 0
         ema = None
         stragglers = 0
-        if self.ckpt.latest_step() is not None:
+        if self.ckpt is not None and self.ckpt.latest_step() is not None:
             proto = jax.eval_shape(lambda s: s,
                                    self._pack(state, scale_state, err))
             tree, start_step = self.ckpt.restore(proto)
@@ -201,15 +236,21 @@ class TrainLoop:
                     next(self.data)
         elif callable(self.data):
             self.data = self.data(0)
+        if self.plan is not None:
+            state = self._shard(state, self.plan.train_state_specs(state))
+            if scale_state is not None:
+                scale_state = self._shard(scale_state,
+                                          replicated(scale_state))
+            if err is not None:
+                err = self._shard(err, self.plan.wire_state_specs(err))
 
         last_metrics: Dict[str, Any] = {}
         step = start_step
         for step in range(start_step, self.loop.total_steps):
             t0 = time.time()
             with self.tracer.span("data_wait", step=step):
-                batch = next(self.data)
-            step_key = jax.random.fold_in(
-                jax.random.PRNGKey(self.seed + 17), step)
+                batch = self.place_batch(next(self.data))
+            step_key = self.step_key(step)
             with self.tracer.span("step_dispatch", step=step):
                 if self.wire and self.scaling is None:
                     (state, err), metrics = self._step_fn(
@@ -244,8 +285,9 @@ class TrainLoop:
                     + (1 - self.loop.straggler_ema) * dt
 
             done = step + 1 >= self.loop.total_steps
-            save = self._stop or done or \
-                (step + 1) % self.loop.checkpoint_every == 0
+            save = self.ckpt is not None and (
+                self._stop or done
+                or (step + 1) % self.loop.checkpoint_every == 0)
             if save:
                 with self.tracer.span("checkpoint", step=step):
                     self.ckpt.save(
@@ -275,10 +317,12 @@ class TrainLoop:
                 scale = f"{scale:.0f}" if isinstance(scale, float) else scale
                 print(f"[train] step {step} loss={loss} scale={scale} "
                       f"t={dt:.3f}s")
-            if self._stop and save:
-                print(f"[train] preempted: checkpointed at {step + 1}")
+            if self._stop:
+                if save:
+                    print(f"[train] preempted: checkpointed at {step + 1}")
                 break
-        self.ckpt.wait()
+        if self.ckpt is not None:
+            self.ckpt.wait()
         return {"state": state, "scale_state": scale_state,
                 "wire_error": err, "last_step": step + 1,
                 "metrics": last_metrics, "stragglers": stragglers}

@@ -78,6 +78,12 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
     cross-device-combined (pmax) inside the shard_map body.
     """
     wire = plan is not None and plan.compresses
+    # XLA cannot partition a Pallas kernel, so under a Pallas backend a
+    # data-parallel step without the fp8 wire still runs its loss/grad pass
+    # inside the wire path's explicit shard_map over the dp axes, and
+    # reduces the grads there in full precision.
+    manual_dp = (plan is not None and not wire and plan.dp_size > 1
+                 and cfg.policy.quant.backend.startswith("pallas"))
 
     def constrain_grads(g):
         if plan is None:
@@ -153,13 +159,16 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                  jax.lax.pmean(tok[..., c:], axes)], axis=-1)
         return jax.lax.pmax(tok, axes)
 
-    def _wire_grads_and_metrics(params, batch, step_key, scale, scale_state):
+    def _wire_grads_and_metrics(params, batch, step_key, scale, scale_state,
+                                reduce_all=False):
         """The fp8-on-the-wire gradient pass: loss/grads computed locally
         inside an explicit shard_map over the dp axes (so the cross-device
         reduction is OURS, not an XLA-inserted all-reduce), full-precision
         pmean over the fast intra-pod axes, then the e5m2 error-feedback
         collective over the wire axis. Returns stacked per-wire-device f32
-        grads (leading axis = wire device) ready for plan.dp_allreduce."""
+        grads (leading axis = wire device) ready for plan.dp_allreduce —
+        or, with `reduce_all`, the grads pmean-reduced over every dp axis
+        in the body (the manual_dp path)."""
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed import sharding as shmod
@@ -179,9 +188,10 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                 loss, metrics, grads, tok_grads = _grads_and_metrics(
                     params_, batch_, key_, scale_, sstate_,
                     constrain=lambda g: g)
-            if inner:
+            if inner or reduce_all:
                 grads = jax.tree_util.tree_map(
-                    lambda g: jax.lax.pmean(g, inner), grads)
+                    lambda g: jax.lax.pmean(g, dp if reduce_all else inner),
+                    grads)
             loss = jax.lax.pmean(loss, dp)
             metrics = {k: (jax.lax.pmax(v, dp)
                            if k.startswith((AMAX_PREFIX, HEALTH_PREFIX))
@@ -189,8 +199,9 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                        for k, v in metrics.items()}
             tok_grads = {k: _combine_tokens(v, dp)
                          for k, v in tok_grads.items()}
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32)[None], grads)
+            if not reduce_all:
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32)[None], grads)
             return loss, metrics, grads, tok_grads
 
         bspecs = plan.batch_specs(batch)
@@ -199,9 +210,18 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
         if scaling is not None:
             operands += (scale_state,)
             in_specs += (P(),)
+        grad_spec = P() if reduce_all else P(plan.wire_axis)
         return plan.shard_map(
-            local_body, in_specs,
-            (P(), P(), P(plan.wire_axis), P()))(*operands)
+            local_body, in_specs, (P(), P(), grad_spec, P()))(*operands)
+
+    def _local_grads(params, batch, step_key, scale, scale_state):
+        if not manual_dp:
+            return _grads_and_metrics(params, batch, step_key, scale,
+                                      scale_state)
+        loss, metrics, grads, tok_grads = _wire_grads_and_metrics(
+            plan.gather_params(params), batch, step_key, scale, scale_state,
+            reduce_all=True)
+        return loss, metrics, constrain_grads(grads), tok_grads
 
     def _finish(state, grads, loss, metrics, scale):
         new_state, opt_metrics = optimizer.apply_gradients(state, grads)
@@ -215,7 +235,7 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                    step_key: Array) -> Tuple[MixedPrecisionState, Dict]:
         params = optimizer.compute_params(state)
         scale = state.loss_scale.scale
-        loss, metrics, grads, _ = _grads_and_metrics(
+        loss, metrics, grads, _ = _local_grads(
             params, batch, step_key, scale, None)
         return _finish(state, grads, loss, metrics, scale)
 
@@ -223,11 +243,12 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                           batch: Dict[str, Array], step_key: Array):
         params = optimizer.compute_params(state)
         scale = state.loss_scale.scale
-        loss, metrics, grads, tok_grads = _grads_and_metrics(
+        loss, metrics, grads, tok_grads = _local_grads(
             params, batch, step_key, scale, scale_state)
         observed = split_observations(metrics, tok_grads, scaling.registry)
-        new_scale_state = scaling.update(scale_state, observed,
-                                         sync=amax_sync)
+        # (manual_dp observations are already pmax-combined in the body.)
+        new_scale_state = scaling.update(
+            scale_state, observed, sync=None if manual_dp else amax_sync)
         new_state, out = _finish(state, grads, loss, metrics, scale)
         if scaling.qcfg.track_health:
             # Scale-churn rate: fraction of registry rows whose derived
@@ -277,6 +298,27 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
     if wire:
         return train_step_wire if scaling is None else train_step_wire_scaled
     return train_step if scaling is None else train_step_scaled
+
+
+def make_loss_eval(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
+                   scaling: Optional[DelayedScaling] = None):
+    """loss_eval(state, scale_state, batch, step_key) -> the loss a train
+    step on the same operands reports (unscaled f32), from the forward pass
+    alone: no gradients, so it fits where the whole step does not.
+    `scale_state` is ignored without `scaling`."""
+    def loss_eval(state: MixedPrecisionState, scale_state, batch, step_key):
+        params = optimizer.compute_params(state)
+        scale = state.loss_scale.scale
+        if scaling is None:
+            loss, _ = lm_loss(params, batch, cfg=cfg, qkey=step_key,
+                              loss_scale=scale)
+        else:
+            with scaling.collect(scale_state, scaling.zero_tokens()):
+                loss, _ = lm_loss(params, batch, cfg=cfg, qkey=step_key,
+                                  loss_scale=scale)
+        return loss.astype(jnp.float32) * (1.0 / jnp.maximum(scale, 1e-9))
+
+    return loss_eval
 
 
 def optax_safe_norm(tree) -> Array:

@@ -299,7 +299,7 @@ class TestPolicyWiring:
     def test_build_cell_meta_records_resolved_blocks(self, monkeypatch):
         import repro.launch.specs as S
         import repro.models.registry as R
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         orig = R.build_config
         monkeypatch.setattr(
             R, "build_config",
@@ -310,7 +310,7 @@ class TestPolicyWiring:
         S._cfg_for_cell.cache_clear()
         try:
             mesh = make_mesh((1, 1), ("data", "model"))
-            with enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = S.build_cell("qwen2-1.5b", "tiny_train", mesh)
                 cell_off = S.build_cell(
                     "qwen2-1.5b", "tiny_train", mesh,

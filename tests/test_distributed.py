@@ -149,8 +149,8 @@ def test_grad_compression_correct_and_error_feedback():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.distributed.grad_compress import compressed_psum_mean
-        from repro.distributed.sharding import shard_map_compat
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.distributed.sharding import shard_map
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((8,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 512)) * 0.01
         e0 = jnp.zeros_like(g)
@@ -159,10 +159,10 @@ def test_grad_compression_correct_and_error_feedback():
                 r, ne = compressed_psum_mean({"g": gl[0]}, {"g": el[0]},
                                              axis_name="pod")
                 return r["g"][None], ne["g"][None]
-            return shard_map_compat(inner, mesh,
-                                    (P("pod", None), P("pod", None)),
-                                    (P("pod", None), P("pod", None)))(g, e)
-        with enter_mesh(mesh):
+            return shard_map(inner, mesh,
+                             (P("pod", None), P("pod", None)),
+                             (P("pod", None), P("pod", None)))(g, e)
+        with jax.set_mesh(mesh):
             red, err = jax.jit(step)(g, e0)
         true = np.asarray(g).mean(0)
         rel = np.linalg.norm(np.asarray(red)[0] - true) / np.linalg.norm(true)
@@ -184,7 +184,7 @@ def test_small_mesh_dryrun_train_and_decode():
     path (param/batch/state specs, SP, ZeRO) on 8 host devices."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.launch.specs import build_cell, SHAPES
         SHAPES["tiny_train"] = dict(seq=64, batch=8, mode="train")
         SHAPES["tiny_decode"] = dict(seq=64, batch=8, mode="decode")
@@ -200,14 +200,11 @@ def test_small_mesh_dryrun_train_and_decode():
                     orig(a, smoke=True, **kw)
                 S._cfg_for_cell.cache_clear()
                 try:
-                    from repro.launch.mesh import jit_shardings
-                    with enter_mesh(mesh):
+                    with jax.set_mesh(mesh):
                         cell = build_cell(arch, shape, mesh)
                         c = jax.jit(cell["fn"],
-                                    in_shardings=jit_shardings(
-                                        mesh, cell["in_shardings"]),
-                                    out_shardings=jit_shardings(
-                                        mesh, cell["out_shardings"])
+                                    in_shardings=cell["in_shardings"],
+                                    out_shardings=cell["out_shardings"]
                                     ).lower(*cell["args"]).compile()
                         assert c.memory_analysis().temp_size_in_bytes > 0
                         print("OK", arch, shape)
@@ -224,7 +221,7 @@ def test_real_sharded_train_step_runs():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.registry import build_config
         from repro.models.transformer import init_lm
         from repro.train.step import make_optimizer_for, make_train_step
@@ -235,7 +232,7 @@ def test_real_sharded_train_step_runs():
             vocab_size=512, remat=False)
         opt = make_optimizer_for(cfg, learning_rate=1e-3)
         step = make_train_step(cfg, opt)
-        with enter_mesh(mesh):
+        with jax.set_mesh(mesh):
             params = init_lm(jax.random.PRNGKey(0), cfg)
             state = opt.init(params)
             toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 512)

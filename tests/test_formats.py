@@ -142,6 +142,70 @@ class TestExhaustiveRoundTrip:
 
 
 # ---------------------------------------------------------------------------
+# the SR path in 32-bit ops vs the f16-cast path it replaced
+# ---------------------------------------------------------------------------
+
+def _sr_via_f16_cast(x, rand, fmt, saturate):
+    """Oracle: SR through real float16 casts (`x.astype(float16)`, the
+    f16 -> fp8 storage cast), as core.quantize did before its f16 steps
+    moved to int32/f32 ops (Mosaic cannot lower f32 -> f16 on v5e)."""
+    spec = Q.sr_spec(fmt)
+    if saturate:
+        lo = jnp.asarray(-fmt.max_normal, x.dtype)
+        hi = jnp.asarray(fmt.max_normal, x.dtype)
+        x = jnp.where(jnp.isnan(x), x, jnp.clip(x, lo, hi))
+    if spec.pre_exp:
+        x = x * jnp.asarray(2.0 ** spec.pre_exp, x.dtype)
+    h = jax.lax.bitcast_convert_type(x.astype(jnp.float16), jnp.uint16)
+    out = jax.lax.bitcast_convert_type(
+        Q.sr_fp8_from_bits(h, rand, fmt, saturate=saturate), jnp.float16)
+    if spec.pre_exp:
+        out = out * jnp.float16(2.0 ** -spec.pre_exp)
+    return out.astype(fmt.dtype)
+
+
+def _dense_f32():
+    """Every sign/exponent/top-7-mantissa pattern of f32, each with low
+    halves that land on, just off and exactly between f16 grid points."""
+    hi = np.arange(1 << 16, dtype=np.uint32) << 16
+    lo = np.array([0x0000, 0x0FFF, 0x1000, 0x1001, 0x3000, 0xFFFF],
+                  np.uint32)
+    return (hi[:, None] | lo[None, :]).reshape(-1).view(np.float32)
+
+
+class TestSRIntegerPath:
+    @pytest.mark.parametrize("saturate", [True, False])
+    @pytest.mark.parametrize("fmt,mldt", FMTS, ids=IDS)
+    def test_bit_identical_to_f16_cast_path(self, fmt, mldt, saturate):
+        x = jnp.asarray(_dense_f32())
+        rand = jax.random.bits(jax.random.PRNGKey(3), x.shape, jnp.uint8)
+        new = jax.jit(lambda v, r: Q.sr_fp8_via_f16(
+            v, r, fmt, saturate=saturate))(x, rand)
+        old = jax.jit(lambda v, r: _sr_via_f16_cast(
+            v, r, fmt, saturate))(x, rand)
+        np.testing.assert_array_equal(_bits_of(new), _bits_of(old))
+
+    @pytest.mark.parametrize("fmt,mldt", FMTS, ids=IDS)
+    def test_bf16_inputs_bit_identical(self, fmt, mldt):
+        x = jnp.asarray(np.arange(1 << 16, dtype=np.uint16)
+                        .view(ml_dtypes.bfloat16))
+        rand = jax.random.bits(jax.random.PRNGKey(4), x.shape, jnp.uint8)
+        np.testing.assert_array_equal(
+            _bits_of(Q.sr_fp8_via_f16(x, rand, fmt)),
+            _bits_of(_sr_via_f16_cast(x, rand, fmt, True)))
+
+    def test_f16_bits_match_cast_exhaustive_over_f16_grid(self):
+        """The int32 f32 -> f16 RNE equals the hardware cast on the dense
+        f32 sample (ties, subnormals, overflow, inf, NaN payloads)."""
+        x = jnp.asarray(_dense_f32())
+        ref = jax.lax.bitcast_convert_type(x.astype(jnp.float16),
+                                           jnp.uint16)
+        np.testing.assert_array_equal(
+            np.asarray(Q._f16_bits_i32(x)),
+            np.asarray(ref).astype(np.int32))
+
+
+# ---------------------------------------------------------------------------
 # overflow semantics per tensor class
 # ---------------------------------------------------------------------------
 

@@ -187,11 +187,11 @@ class TestSpecsVmemGate:
         (the autotuner table owns those; the lint's vmem_fit pass still
         checks them)."""
         S = _smoke_specs(monkeypatch)
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         monkeypatch.setattr(vm, "VMEM_BYTES", 1)
         try:
             mesh = make_mesh((1, 1), ("data", "model"))
-            with enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = S.build_cell("qwen2-1.5b", "tiny_train", mesh)
             assert "attn_block_q" in cell["meta"]
         finally:

@@ -541,7 +541,7 @@ class TestSpecsDelayedCell:
         whole step (the same abstract proof the dry-run lowers)."""
         import repro.launch.specs as S
         import repro.models.registry as R
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.scaling.state import ScaleState
 
         orig = R.build_config
@@ -554,7 +554,7 @@ class TestSpecsDelayedCell:
         S._cfg_for_cell.cache_clear()
         try:
             mesh = make_mesh((1, 1), ("data", "model"))
-            with enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = S.build_cell(
                     "qwen2-1.5b", "tiny_train", mesh,
                     overrides={"policy.quant.recipe": "hybrid",
@@ -574,7 +574,7 @@ class TestSpecsDelayedCell:
     def test_build_cell_default_unchanged(self, monkeypatch):
         import repro.launch.specs as S
         import repro.models.registry as R
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         orig = R.build_config
         monkeypatch.setattr(
             R, "build_config",
@@ -585,7 +585,7 @@ class TestSpecsDelayedCell:
         S._cfg_for_cell.cache_clear()
         try:
             mesh = make_mesh((1, 1), ("data", "model"))
-            with enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = S.build_cell("qwen2-1.5b", "tiny_train", mesh)
         finally:
             S._cfg_for_cell.cache_clear()

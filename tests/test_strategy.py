@@ -132,10 +132,9 @@ class TestPlanComposition:
 # ---- 8-device behavior (subprocesses force the host platform) --------------
 
 def test_wire_collectives_8dev():
-    """The satellite bugfix regression: the compressed all-reduce must
-    lower through shard_map_compat on this JAX (jax.shard_map does not
-    exist on 0.4.37), put real 1-byte f8 payloads in the HLO, and the fp8
-    zero-gather + TP-refusal gates must behave."""
+    """The compressed all-reduce must lower through the plan's shard_map,
+    put real 1-byte f8 payloads in the HLO, and the fp8 zero-gather +
+    TP-refusal gates must behave."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -179,7 +178,7 @@ def test_wire_collectives_8dev():
         assert relg < 0.10, relg
         print("OK gather", relg)
 
-        # 3. fp8 wire + active TP is refused with a clear error on this JAX.
+        # 3. fp8 wire + active TP is refused with a clear error.
         meshtp = make_mesh((2, 4), ("data", "model"))
         try:
             ParallelPlan.build(meshtp, DistConfig(wire="fp8_ef"))
@@ -204,7 +203,7 @@ def test_wire_train_convergence_law():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.precision_policy import DistConfig
         from repro.distributed.strategy import ParallelPlan
-        from repro.launch.mesh import enter_mesh, make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.registry import build_config
         from repro.models.transformer import init_lm
         from repro.train.step import make_optimizer_for, make_train_step
@@ -226,7 +225,7 @@ def test_wire_train_convergence_law():
         batch = {"tokens": toks, "labels": toks,
                  "loss_mask": np.ones((16, 32), np.float32)}
         rels, losses = [], []
-        with enter_mesh(mesh):
+        with jax.set_mesh(mesh):
             for i in range(12):
                 k = jax.random.fold_in(jax.random.PRNGKey(7), i)
                 sf, mf = step_f(sf, batch, k)
@@ -305,7 +304,7 @@ def test_wire_build_cell_hierarchical_mesh():
     wire accounting in meta."""
     out = _run_subprocess("""
         import jax
-        from repro.launch.mesh import enter_mesh, jit_shardings, make_mesh
+        from repro.launch.mesh import make_mesh
         import repro.launch.specs as S
         import repro.models.registry as R
         S.SHAPES["tiny_train"] = dict(seq=64, batch=8, mode="train")
@@ -314,7 +313,7 @@ def test_wire_build_cell_hierarchical_mesh():
         S._cfg_for_cell.cache_clear()
         try:
             mesh = make_mesh((2, 4), ("pod", "data"))
-            with enter_mesh(mesh):
+            with jax.set_mesh(mesh):
                 cell = S.build_cell(
                     "qwen2-1.5b", "tiny_train", mesh,
                     overrides={"policy.dist.wire": "fp8_ef",
@@ -325,15 +324,64 @@ def test_wire_build_cell_hierarchical_mesh():
                 assert meta["wire_bytes"]["ratio_fp8_vs_bf16"] <= 0.55
                 assert len(cell["args"]) == 4   # state, err, batch, key
                 c = jax.jit(cell["fn"],
-                            in_shardings=jit_shardings(
-                                mesh, cell["in_shardings"]),
-                            out_shardings=jit_shardings(
-                                mesh, cell["out_shardings"])
+                            in_shardings=cell["in_shardings"],
+                            out_shardings=cell["out_shardings"]
                             ).lower(*cell["args"]).compile()
                 hlo = c.as_text()
                 assert "f8e5m2" in hlo   # wire payloads are really 1 byte
                 print("OK", meta["dist"])
         finally:
             R.build_config = orig
+    """)
+    assert "OK" in out
+
+
+def test_pallas_data_parallel_step_runs_in_shard_map():
+    """XLA cannot partition a Pallas kernel, so with a Pallas backend the
+    full-precision data-parallel step runs its loss/grad pass inside an
+    explicit shard_map; TrainLoop splits each batch over the dp devices
+    and trains."""
+    out = _run_subprocess("""
+        import jax, numpy as np
+        from repro.analysis import jaxpr_walk as jw
+        from repro.distributed.strategy import ParallelPlan
+        from repro.launch.mesh import make_mesh
+        from repro.launch.train import make_train_loop, train_config
+        from repro.models.transformer import init_lm
+        from repro.train.step import make_train_step
+
+        cfg, _ = train_config("qwen2-1.5b", smoke=True, overrides=(
+            "n_layers=1", "d_model=64", "n_heads=4", "n_kv_heads=2",
+            "d_ff=128", "vocab_size=128", "policy.quant.recipe=hybrid",
+            "policy.quant.scaling=delayed",
+            "policy.quant.backend=pallas_interpret"))
+        plan = ParallelPlan.build(make_mesh((8,), ("data",)),
+                                  cfg.policy.dist)
+        assert not plan.compresses
+        loop = make_train_loop(cfg, steps=2, batch=8, seq=16, plan=plan)
+
+        tokens = jax.ShapeDtypeStruct((8, 16), np.int32)
+        batch = {"tokens": tokens, "labels": tokens}
+        state = jax.eval_shape(lambda: loop.optimizer.init(
+            init_lm(jax.random.PRNGKey(0), cfg)))
+        step = make_train_step(cfg, loop.optimizer, scaling=loop.scaling,
+                               plan=plan)
+        jaxpr = jax.make_jaxpr(step)(
+            state, jax.eval_shape(loop.scaling.init), batch,
+            jax.ShapeDtypeStruct((2,), np.uint32))
+        names = [e.primitive.name for e in jw.all_eqns(jaxpr)]
+        assert names.count("shard_map") == 1, names.count("shard_map")
+
+        placed = loop.place_batch({"tokens": np.zeros((8, 16), np.int32)})
+        shards = placed["tokens"].addressable_shards
+        assert len({s.device for s in shards}) == 8
+        assert all(s.data.shape == (1, 16) for s in shards)
+
+        records = []
+        loop.on_metrics = lambda s, r: records.append(r)
+        loop.run()
+        losses = [r["loss"] for r in records]
+        assert len(losses) == 2 and np.isfinite(losses).all(), losses
+        print("OK", losses)
     """)
     assert "OK" in out

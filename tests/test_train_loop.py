@@ -9,7 +9,8 @@ from repro.train.loop import LoopConfig, TrainLoop
 from repro.train.step import make_optimizer_for
 
 
-def _loop(tmp_path, total_steps, vocab=128, seed=0, metrics=None):
+def _loop(tmp_path, total_steps, vocab=128, seed=0, metrics=None,
+          checkpoints=True):
     cfg = build_config("qwen2-1.5b", smoke=True).replace(
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, d_ff=128,
         vocab_size=vocab, remat=False)
@@ -19,7 +20,8 @@ def _loop(tmp_path, total_steps, vocab=128, seed=0, metrics=None):
     data = synthetic_lm_batches(DataConfig(
         vocab_size=vocab, seq_len=32, batch_size=8, seed=seed))
     loop = LoopConfig(total_steps=total_steps, checkpoint_every=5,
-                      checkpoint_dir=str(tmp_path / "ckpt"),
+                      checkpoint_dir=(str(tmp_path / "ckpt") if checkpoints
+                                      else None),
                       log_every=100, metrics_path=metrics)
     return TrainLoop(cfg, opt, data, loop, seed=seed)
 
@@ -95,3 +97,39 @@ def test_straggler_detection(tmp_path):
     lp._step_fn = wrapped
     out = lp.run()
     assert out["stragglers"] >= 1
+
+
+def test_loss_eval_is_the_first_step_loss(tmp_path):
+    """make_loss_eval reports the loss the first train step reports, from
+    the forward pass alone (same weights, batch, scales, key)."""
+    import dataclasses
+
+    import jax
+
+    from repro.launch.train import make_train_loop, train_config
+    from repro.models.transformer import init_lm
+    from repro.train.step import make_loss_eval
+
+    cfg, _ = train_config("qwen2-1.5b", smoke=True, overrides=(
+        "n_layers=2", "d_model=64", "n_heads=4", "n_kv_heads=2", "d_ff=128",
+        "vocab_size=128", "policy.quant.recipe=hybrid",
+        "policy.quant.scaling=delayed"))
+    loop = make_train_loop(cfg, steps=1, batch=2, seq=16, seed=3)
+    state = dataclasses.replace(
+        loop.optimizer.init(init_lm(jax.random.PRNGKey(3), cfg)),
+        opt_state=None)
+    batch = next(synthetic_lm_batches(DataConfig(
+        vocab_size=128, seq_len=16, batch_size=2, seed=3)))
+    ref = jax.jit(make_loss_eval(cfg, loop.optimizer, scaling=loop.scaling))(
+        state, loop.scaling.init(), batch, loop.step_key(0))
+    records = []
+    loop.on_metrics = lambda step, rec: records.append(rec)
+    loop.run()
+    np.testing.assert_allclose(records[0]["loss"], float(ref), rtol=1e-6)
+
+
+def test_no_checkpoint_dir_means_no_checkpoints(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    out = _loop(tmp_path, 6, checkpoints=False).run()
+    assert out["last_step"] == 6
+    assert list(tmp_path.iterdir()) == []
