@@ -1,0 +1,13 @@
+"""Least time of the training step's FP8 GEMMs (bench.counts, bf16 peak
+and HBM bandwidth) over the device time of the fused_quant_matmul kernels."""
+from bench import counts, trace
+
+
+def read(ctx):
+    w = ctx["work"]
+    ker = trace.kernel_s(ctx["trace"], "fused_quant_matmul")
+    if w["kind"] != "train" or ker <= 0:
+        return None
+    least = w["steps"] * counts.train_gemm_least_time(
+        w["z"], w["batch"] * w["seq"], ctx["peaks"])
+    return 100.0 * least / ker
