@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.core.precision_policy import ACT, ERROR, QuantConfig, dtype_of
 from repro.core.qlinear import _health, _observe, _quant_operand, _track
+from repro.obs.trace import scope
 from repro.scaling import context as scale_ctx
 
 Array = jax.Array
@@ -114,7 +115,8 @@ def _fp8_sdpa_fwd(cfg, mask_mode, window, sm_scale, q, k, v, key, scales,
     v8 = _quant_operand(v, ACT, cfg, k_v, scale=scales[2])
     # In-kernel SR bits come from a counter hash of this seed + absolute
     # coordinates (no rand array in HBM; bits are tiling-invariant).
-    seed = jax.random.bits(k_seed, (), jnp.uint32)
+    with scope("fp8.sr_bits"):
+        seed = jax.random.bits(k_seed, (), jnp.uint32)
     outs = attn_ops.fp8_attention_fwd(
         q8.data, k8.data, v8.data, seed, _fwd_factors(scales, sm_scale),
         mask_mode=mask_mode, window=window, with_counts=_track(cfg),

@@ -49,6 +49,7 @@ from repro.core.fp8_formats import get_format
 from repro.core.precision_policy import (ACT, ERROR, GRAD, WEIGHT, PAPER_FP8,
                                          QuantConfig, dtype_of)
 from repro.obs.counters import payload_health
+from repro.obs.trace import scope
 from repro.scaling import context as scale_ctx
 
 Array = jax.Array
@@ -96,6 +97,7 @@ def adjoint_specs(spec: str) -> Tuple[str, str]:
 # operand quantization + fp8 compute
 # ---------------------------------------------------------------------------
 
+@scope("fp8.quant")
 def _quant_operand(x: Array, cls: str, cfg: QuantConfig, key: Array,
                    scale: Optional[Array] = None) -> QTensor:
     """Quantize one operand. With delayed scaling, `scale` is the
@@ -205,6 +207,7 @@ def _plain_einsum(spec: str, a: Array, b: Array, cfg: QuantConfig) -> Array:
 # custom_vjp core
 # ---------------------------------------------------------------------------
 
+@scope("fp8.amax")
 def _observe(q: QTensor, cfg: QuantConfig) -> Array:
     """Observed amax of a quantized operand, from the FP8 payload's bit
     patterns (uint8 reduce — no pass over the high-precision tensor)."""
@@ -219,6 +222,7 @@ def _track(cfg: QuantConfig) -> bool:
     return cfg.track_health and cfg.delayed
 
 
+@scope("fp8.amax")
 def _health(q: QTensor, cfg: QuantConfig, cls: str) -> Array:
     """(sat_frac, flush_frac) of a quantized operand, from the same uint8
     payload read `_observe` performs — XLA fuses the two reductions into

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fp8_formats import E4M3, E5M2, FloatFormat, get_format
+from repro.obs.trace import scope
 
 Array = jax.Array
 
@@ -275,11 +276,13 @@ def sr_fp8_via_f16(x: Array, rand: Array, fmt: FloatFormat = E5M2, *,
     return _fp8_from_f16_bits(out_bits, fmt)
 
 
+@scope("fp8.quant")
 def quantize_sr_fp8(x: Array, key: Array, fmt: FloatFormat = E5M2, *,
                     saturate: bool = True) -> Array:
     """Stochastically round into an fp16-embeddable fp8 format (exact on the
     fp16 grid — the paper's SR, format-generalized)."""
-    rand = jax.random.bits(key, x.shape, jnp.uint16)
+    with scope("fp8.sr_bits"):
+        rand = jax.random.bits(key, x.shape, jnp.uint16)
     return sr_fp8_via_f16(x, rand, fmt, saturate=saturate)
 
 
@@ -288,6 +291,7 @@ def quantize_sr_e5m2(x: Array, key: Array, *, saturate: bool = True) -> Array:
     return quantize_sr_fp8(x, key, E5M2, saturate=saturate)
 
 
+@scope("fp8.quant")
 def quantize_sr_grid(x: Array, fmt: FloatFormat, key: Array, *,
                      saturate: bool = True) -> Array:
     """Generic grid-based stochastic rounding (any format, e.g. E4M3).
@@ -304,7 +308,8 @@ def quantize_sr_grid(x: Array, fmt: FloatFormat, key: Array, *,
     e = jnp.maximum(e_unb, fmt.min_exp)
     ulp_exp = e - fmt.man_bits
     ulp = jnp.exp2(ulp_exp.astype(jnp.float32))
-    r = jax.random.uniform(key, xf.shape, jnp.float32)
+    with scope("fp8.sr_bits"):
+        r = jax.random.uniform(key, xf.shape, jnp.float32)
     q = jnp.floor(ax / ulp + r) * ulp
     if saturate:
         q = jnp.minimum(q, fmt.max_normal)
@@ -358,6 +363,7 @@ class QTensor:
             self.data.astype(jnp.float32) * self.scale[..., None].astype(jnp.float32)
 
 
+@scope("fp8.amax")
 def fp8_amax_bits(data: Array) -> Array:
     """amax of an FP8 tensor via its bit patterns — the delayed-scaling
     observation primitive. For sign-cleared fp8 encodings the bit pattern is
@@ -371,6 +377,7 @@ def fp8_amax_bits(data: Array) -> Array:
         .astype(jnp.float32)
 
 
+@scope("fp8.amax")
 def amax_scale(x: Array, fmt: FloatFormat, *, margin: float = 1.0) -> Array:
     """Per-tensor scale mapping amax -> fmt.max_normal / margin. The abs/max
     reduce stays in x's dtype (no f32 copy); only the scalar is f32."""
@@ -379,6 +386,7 @@ def amax_scale(x: Array, fmt: FloatFormat, *, margin: float = 1.0) -> Array:
     return amax * margin / fmt.max_normal
 
 
+@scope("fp8.quant")
 def quantize(x: Array, fmt: Union[str, FloatFormat] = E5M2, *,
              rounding: str = "rne",
              key: Optional[Array] = None,

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.kernels import autotune as _at
 from repro.kernels.fused_quant_matmul import kernel as _k
+from repro.obs.trace import scope
 
 
 def _pad_to(x, mult0, mult1):
@@ -83,8 +84,9 @@ def fused_quant_matmul(a, b, key, scale=None, *,
         ap, bp = _pad_to(a, bk_, bm_), _pad_to(b, bk_, bn_)
     # Draw SR bits for the logical cells only; padded cells get zero bits
     # (their zero accumulator then stays exactly zero under SR truncation).
-    rand8 = jax.random.bits(key, (m, n), jnp.uint8) if rounding == "sr" \
-        else jnp.zeros((m, n), jnp.uint8)
+    with scope("fp8.sr_bits"):
+        rand8 = jax.random.bits(key, (m, n), jnp.uint8) \
+            if rounding == "sr" else jnp.zeros((m, n), jnp.uint8)
     rand8 = _pad_to(rand8, bm_, bn_)
     out = _k.fused_quant_matmul_kernel(ap, bp, rand8, scale,
                                        dims=dims, bm=bm_, bk=bk_, bn=bn_,
@@ -96,13 +98,14 @@ def fused_quant_matmul(a, b, key, scale=None, *,
                                        interpret=interpret)
     if with_amax:
         health = None
-        if with_counts:
-            out, tile_amax, tile_sat, tile_flush = out
-            health = jnp.stack([jnp.sum(tile_sat), jnp.sum(tile_flush)]) \
-                / jnp.float32(m * n)
-        else:
-            out, tile_amax = out
-        amax = jnp.max(tile_amax)
+        with scope("fp8.amax"):
+            if with_counts:
+                out, tile_amax, tile_sat, tile_flush = out
+                health = jnp.stack([jnp.sum(tile_sat),
+                                    jnp.sum(tile_flush)]) / jnp.float32(m * n)
+            else:
+                out, tile_amax = out
+            amax = jnp.max(tile_amax)
         if amax_units == "real":
             amax = amax * scale[0]
         elif amax_units != "grid":

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.stochastic_round import kernel as _k
+from repro.obs.trace import scope
 
 
 @functools.partial(jax.jit,
@@ -34,7 +35,8 @@ def stochastic_round_fp8(x, key, scale=None, *, fmt: str = "e5m2",
         out = _k.sr_quantize_kernel_onchip(x2, seed, scale, fmt=fmt,
                                            saturate=saturate)
     else:
-        rand8 = jax.random.bits(key, x2.shape, jnp.uint8)
+        with scope("fp8.sr_bits"):
+            rand8 = jax.random.bits(key, x2.shape, jnp.uint8)
         out = _k.sr_quantize_kernel(x2, rand8, scale, fmt=fmt,
                                     saturate=saturate, interpret=interpret)
     return out.reshape(orig_shape)

@@ -51,6 +51,7 @@ def make_train_loop(cfg, *, steps: int, batch: int, seq: int,
     from repro.core.loss_scale import LossScaler
     from repro.data import DataConfig, synthetic_lm_batches
     from repro.models.transformer import init_lm
+    from repro.obs.trace import setup_span
     from repro.scaling import DelayedScaling, discover_lm_sites
     from repro.train.loop import LoopConfig, TrainLoop
     from repro.train.step import make_optimizer_for
@@ -65,8 +66,9 @@ def make_train_loop(cfg, *, steps: int, batch: int, seq: int,
         params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(seed), cfg))
         tokens = jax.ShapeDtypeStruct((batch // microbatches, seq), jnp.int32)
         proto = {"tokens": tokens, "labels": tokens}
-        scaling = DelayedScaling(discover_lm_sites(cfg, params, proto),
-                                 qcfg=cfg.policy.quant)
+        with setup_span("discover_sites"):
+            sites = discover_lm_sites(cfg, params, proto)
+        scaling = DelayedScaling(sites, qcfg=cfg.policy.quant)
     loop = LoopConfig(
         total_steps=steps, checkpoint_every=max(10, steps // 4),
         checkpoint_dir=ckpt_dir, log_every=log_every,

@@ -7,8 +7,9 @@ The subsystem has four layers (see docs/metrics_schema.md):
    (zero extra HBM passes; kernel paths count in VMEM next to amax).
  * metrics   — typed MetricsLogger: versioned-schema jsonl sink with
    scalar/vector-aware serialization and rolling-window aggregation.
- * trace     — phase spans (data-wait / step-dispatch / device-sync /
-   checkpoint) with a perfetto-compatible trace export.
+ * trace     — one measurement system: device scopes of the compiled step
+   (`SCOPES`, mapped to trace ops by `op_scopes`), host phase spans on the
+   profiler's clock (`Tracer`), and compile counters.
  * health    — anomaly detectors over the metrics stream (loss-scale
    flapping, saturation, stuck/NaN amax, straggler streaks), surfaced as
    structured `health_events` records.

@@ -34,6 +34,7 @@ import ml_dtypes
 import numpy as np
 
 from repro.core.fp8_formats import FloatFormat, get_format
+from repro.obs.trace import scope
 
 _ML_DTYPE = {"e5m2": ml_dtypes.float8_e5m2, "e4m3": ml_dtypes.float8_e4m3fn}
 
@@ -53,6 +54,7 @@ def payload_thresholds(fmt_name: str) -> Tuple[int, int]:
     return lo, hi
 
 
+@scope("fp8.amax")
 def payload_health(data: jax.Array, fmt_name: str) -> jax.Array:
     """(2,) f32 [sat_frac, flush_frac] from an FP8 payload's bit patterns."""
     lo, hi = payload_thresholds(fmt_name)

@@ -11,7 +11,7 @@ active slot; finished sequences (EOS or max_len) free their slot. The jitted
 decode step is shape-stable — request churn never recompiles.
 
 Observability: prefill and decode run inside `obs.trace.Tracer` spans
-(perfetto-exportable via `engine.tracer`), per-request prefill/decode
+(`repro.serve.<name>` on the profiler's clock), per-request prefill/decode
 latencies and KV-slot occupancy accumulate into rolling windows, and
 `stats()` snapshots the serving counters (latency percentiles, decode
 tokens/s, occupancy) in the same jsonable shape the metrics pipeline and
@@ -90,7 +90,7 @@ class ServeEngine:
         self.last_token = np.zeros((b,), np.int32)
         self._uid = 0
         # -- serving counters (host wall-clock; window bounds memory) --------
-        self.tracer = Tracer()
+        self.tracer = Tracer("repro.serve")
         win = 512
         self._prefill_lat = collections.deque(maxlen=win)
         self._decode_lat = collections.deque(maxlen=win)
@@ -401,7 +401,7 @@ class PagedServeEngine:
         b = serve.max_batch
         self.slots: List[Optional[_PagedRequest]] = [None] * b
         self._uid = 0
-        self.tracer = Tracer()
+        self.tracer = Tracer("repro.serve")
         win = 512
         self._prefill_lat = collections.deque(maxlen=win)
         self._step_lat = collections.deque(maxlen=win)

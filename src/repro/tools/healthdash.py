@@ -155,8 +155,9 @@ def _events_section(records: List[Dict[str, Any]], cap: int = 40) -> List[str]:
 def _comms_section(records: List[Dict[str, Any]],
                    meta: Optional[Dict[str, Any]] = None) -> List[str]:
     """Wire-format communication stream (distributed runs): per-step wire
-    bytes of the DP gradient reduction plus the sampled span/allreduce_s
-    timing probe. Absent entirely for single-device runs."""
+    bytes of the DP gradient reduction (its device time is in a profiler
+    trace, under the `train.allreduce` scope). Absent entirely for
+    single-device runs."""
     comm_keys = sorted({k for r in records for k in r
                         if k.startswith(COMM_PREFIX)})
     if not comm_keys:
@@ -178,11 +179,6 @@ def _comms_section(records: List[Dict[str, Any]],
                      f"({_fmt(bps * n_steps, '.4g')} over {n_steps} steps)")
     if isinstance(ratio, (int, float)):
         lines.append(f"- fp8_ef vs bf16 wire ratio: {_fmt(ratio, '.3f')}")
-    ar = [r["span/allreduce_s"] for r in records
-          if isinstance(r.get("span/allreduce_s"), (int, float))]
-    if ar:
-        lines.append(f"- allreduce probe: p50 {_fmt(_pct(ar, 50))} s, "
-                     f"p99 {_fmt(_pct(ar, 99))} s (n={len(ar)} samples)")
     return lines
 
 

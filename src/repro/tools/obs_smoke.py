@@ -5,12 +5,14 @@
 Runs a short delayed-scaling FP8 training with precision-health counters ON
 (QuantConfig.track_health): per-site saturation/flush fractions flow from
 the payload-bit readers and kernel epilogues through the metrics pipeline,
-phase spans and health events land in the jsonl, and the perfetto trace
-exports next to it. Artifacts (uploaded by CI, consumed by healthdash):
+phase spans and health events land in the jsonl, and a JAX profiler trace
+of the run (host spans and device ops on one clock) is recorded next to it.
+Artifacts (uploaded by CI, consumed by healthdash):
 
   <out_dir>/nightly_smoke.jsonl            one record per step
   <out_dir>/nightly_smoke.jsonl.meta.json  schema version + run meta
-  <out_dir>/nightly_smoke_trace.json       perfetto trace events
+  <out_dir>/nightly_smoke_trace/           profiler trace (xplane.pb and
+                                           perfetto_trace.json.gz)
 """
 from __future__ import annotations
 
@@ -79,7 +81,7 @@ def main(argv=None) -> int:
             total_steps=args.steps, checkpoint_every=max(4, args.steps // 2),
             checkpoint_dir=ckpt_dir, log_every=5,
             metrics_path=str(out / "nightly_smoke.jsonl"),
-            trace_path=str(out / "nightly_smoke_trace.json"))
+            trace_path=str(out / "nightly_smoke_trace"))
         result = TrainLoop(cfg, opt, data_at, loop, seed=0,
                            scaling=scaling).run()
     rec = result["metrics"]

@@ -14,19 +14,26 @@ Production behaviors for the 1000-node regime, exercised at CPU scale:
    lands on a healthy machine). EMA and straggler count ride the checkpoint
    manifest, so a resumed run keeps its timing baseline instead of
    re-learning it (and mis-flagging the first post-restore steps).
- * observability — each step's phases run inside `obs.trace.Tracer` spans
-   (data_wait / step_dispatch / device_sync / checkpoint), metrics stream
-   through `obs.metrics.MetricsLogger` (versioned-schema jsonl; vector
-   metrics such as per-layer amax trajectories serialize as lists), and
-   `obs.health.HealthMonitor` attaches structured `health_events` (overflow,
-   loss-scale flapping, per-site FP8 saturation/underflow, stuck amax,
-   straggler streaks) to the record that triggered them. The `on_metrics`
-   hook sees every serialized record.
+ * observability — each step runs inside a profiler
+   `StepTraceAnnotation("train", step_num=step)`, and its phases inside
+   `obs.trace.Tracer` spans (data_wait / step_dispatch / device_sync /
+   checkpoint / record / on_metrics), each timed into the record and marked
+   `repro.train.<name>` on the profiler's clock; the compiled step is
+   scoped by phase (`obs.trace.SCOPES`). With `LoopConfig.trace_path` set,
+   `run()` records a JAX profiler trace (xplane plus a perfetto trace) in
+   that directory. Metrics stream through `obs.metrics.MetricsLogger`
+   (versioned-schema jsonl; vector metrics such as per-layer amax
+   trajectories serialize as lists), each record carrying the process's
+   cumulative `compiles`, and `obs.health.HealthMonitor` attaches
+   structured `health_events` (overflow, loss-scale flapping, per-site FP8
+   saturation/underflow, stuck amax, straggler streaks) to the record that
+   triggered them. The `on_metrics` hook sees every serialized record.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import signal
 import time
 from typing import Any, Callable, Dict, Iterator, Optional
@@ -41,9 +48,9 @@ from repro.core.master_weights import MixedPrecisionOptimizer
 from repro.distributed.sharding import replicated
 from repro.models.config import ModelConfig
 from repro.models.transformer import init_lm
+from repro.obs import trace as obs_trace
 from repro.obs.health import HealthConfig, HealthMonitor
 from repro.obs.metrics import MetricsLogger, jsonable
-from repro.obs.trace import Tracer
 from repro.scaling.state import DelayedScaling
 from repro.train.step import make_train_step
 
@@ -58,7 +65,7 @@ class LoopConfig:
     keep_last_k: int = 3
     log_every: int = 10
     metrics_path: Optional[str] = None
-    trace_path: Optional[str] = None
+    trace_path: Optional[str] = None    # directory for a profiler trace
     metrics_window: int = 64
     straggler_factor: float = 3.0
     straggler_ema: float = 0.95
@@ -84,8 +91,8 @@ class TrainLoop:
         "fp8_ef") the DP reduction runs over the fp8 error-feedback
         collective — the residual pytree then rides the step like
         ScaleState does (checkpointed under "wire_error", restored on
-        resume) and the loop emits comm/* metrics plus a sampled
-        span/allreduce_s timing probe.
+        resume) and the loop emits comm/* metrics; the reduction runs
+        inside the step under the `train.allreduce` scope.
 
         on_metrics(step, record): called with every serialized metrics
         record (the exact dict written to the jsonl sink, health_events
@@ -111,14 +118,9 @@ class TrainLoop:
             cfg, optimizer, n_microbatches=loop.n_microbatches,
             scaling=scaling, amax_sync=amax_sync, plan=plan),
             donate_argnums=tuple(range(n_carried)))
-        # Timing probe for the wire collective: the step is ONE jitted
-        # program, so the reduction cannot be timed from the host inside
-        # it — instead a standalone jit of the same collective runs on the
-        # (grad-shaped) residual pytree every log_every steps, under
-        # span/allreduce_s.
-        self._wire_probe = jax.jit(plan.dp_allreduce()) if self.wire else None
+        self._step_args = None   # ShapeDtypeStructs of the last call
         self._comm: Dict[str, float] = {}
-        self.tracer = Tracer(loop.trace_path)
+        self.tracer = obs_trace.Tracer("repro.train")
         self.monitor = HealthMonitor(
             health,
             site_names=list(scaling.registry.keys) if scaling else None,
@@ -184,29 +186,40 @@ class TrainLoop:
             return batch
         return self._shard(batch, self.plan.batch_specs(batch))
 
+    def step_text(self) -> str:
+        """Optimized HLO text of the jitted step for the operands of its
+        last call, lowered from ShapeDtypeStructs (no donated buffer is
+        touched). Map it to phases with `obs.trace.op_scopes`."""
+        if self._step_args is None:
+            raise RuntimeError("the loop has not run a step yet")
+        return _compiled_text(self._step_fn, self._step_args)
+
     def run(self) -> Dict[str, Any]:
         # Under a plan the step runs with its mesh installed, so the model's
         # activation constraints apply, and every operand is placed by the
         # plan: state per its ZeRO-1 layout, each batch over the dp axes.
         mesh = jax.set_mesh(self.plan.mesh) if self.plan is not None \
             else contextlib.nullcontext()
-        with mesh, MetricsLogger(self.loop.metrics_path,
-                                 meta=self._logger_meta(),
-                                 window=self.loop.metrics_window) as logger:
-            try:
-                return self._run(logger)
-            finally:
-                self.tracer.export()
+        profile = jax.profiler.trace(self.loop.trace_path,
+                                     create_perfetto_trace=True) \
+            if self.loop.trace_path else contextlib.nullcontext()
+        with mesh, profile, MetricsLogger(
+                self.loop.metrics_path, meta=self._logger_meta(),
+                window=self.loop.metrics_window) as logger:
+            return self._run(logger)
 
     def _run(self, logger: MetricsLogger) -> Dict[str, Any]:
-        state = self.optimizer.init(
-            init_lm(jax.random.PRNGKey(self.seed), self.cfg))
-        if self.plan is not None:
-            # Shard before anything else is allocated: the whole state
-            # was just made on one device.
-            state = self._shard(state, self.plan.train_state_specs(state))
-        scale_state = self.scaling.init() if self.scaling else None
-        err = self.plan.init_wire_state(state.master) if self.wire else None
+        with obs_trace.setup_span("init_state"):
+            state = self.optimizer.init(
+                init_lm(jax.random.PRNGKey(self.seed), self.cfg))
+            if self.plan is not None:
+                # Shard before anything else is allocated: the whole state
+                # was just made on one device.
+                state = self._shard(state,
+                                    self.plan.train_state_specs(state))
+            scale_state = self.scaling.init() if self.scaling else None
+            err = self.plan.init_wire_state(state.master) if self.wire \
+                else None
         if self.wire:
             self._comm = {f"comm/{k}": v for k, v in
                           self.plan.wire_bytes(state.master).items()
@@ -246,68 +259,70 @@ class TrainLoop:
 
         last_metrics: Dict[str, Any] = {}
         step = start_step
+        batch = step_key = None
         for step in range(start_step, self.loop.total_steps):
-            t0 = time.time()
-            with self.tracer.span("data_wait", step=step):
-                batch = self.place_batch(next(self.data))
-            step_key = self.step_key(step)
-            with self.tracer.span("step_dispatch", step=step):
-                if self.wire and self.scaling is None:
-                    (state, err), metrics = self._step_fn(
-                        state, err, batch, step_key)
-                elif self.wire:
-                    (state, scale_state, err), metrics = self._step_fn(
-                        state, scale_state, err, batch, step_key)
-                elif self.scaling is None:
-                    state, metrics = self._step_fn(state, batch, step_key)
-                else:
-                    (state, scale_state), metrics = self._step_fn(
-                        state, scale_state, batch, step_key)
-            with self.tracer.span("device_sync", step=step):
-                metrics = jax.block_until_ready(metrics)
-            if self.wire and step % self.loop.log_every == 0:
-                # Sampled wire-collective timing: the residual pytree is
-                # exactly grad-shaped, so reducing it exercises the real
-                # program (result discarded; error buffers untouched).
-                with self.tracer.span("allreduce", step=step):
-                    jax.block_until_ready(self._wire_probe(err, err))
-            dt = time.time() - t0
-            # straggler detection (skip the compile step)
-            if step > start_step:
-                if ema is not None and dt > self.loop.straggler_factor * ema:
-                    stragglers += 1
-                    print(f"[train] straggler step {step}: {dt:.3f}s vs "
-                          f"EMA {ema:.3f}s")
-                    if self.on_straggler:
-                        self.on_straggler(step, dt)
-                ema = dt if ema is None else \
-                    self.loop.straggler_ema * ema \
-                    + (1 - self.loop.straggler_ema) * dt
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                t0 = time.time()
+                with self.tracer.span("data_wait"):
+                    batch = self.place_batch(next(self.data))
+                with self.tracer.span("step_dispatch"):
+                    step_key = self.step_key(step)
+                    if self.wire and self.scaling is None:
+                        (state, err), metrics = self._step_fn(
+                            state, err, batch, step_key)
+                    elif self.wire:
+                        (state, scale_state, err), metrics = self._step_fn(
+                            state, scale_state, err, batch, step_key)
+                    elif self.scaling is None:
+                        state, metrics = self._step_fn(state, batch, step_key)
+                    else:
+                        (state, scale_state), metrics = self._step_fn(
+                            state, scale_state, batch, step_key)
+                with self.tracer.span("device_sync"):
+                    metrics = jax.block_until_ready(metrics)
+                dt = time.time() - t0
+                # straggler detection (skip the compile step)
+                if step > start_step:
+                    slow = self.loop.straggler_factor * (ema or 0.0)
+                    if ema is not None and dt > slow:
+                        stragglers += 1
+                        print(f"[train] straggler step {step}: {dt:.3f}s vs "
+                              f"EMA {ema:.3f}s")
+                        if self.on_straggler:
+                            self.on_straggler(step, dt)
+                    ema = dt if ema is None else \
+                        self.loop.straggler_ema * ema \
+                        + (1 - self.loop.straggler_ema) * dt
 
-            done = step + 1 >= self.loop.total_steps
-            save = self.ckpt is not None and (
-                self._stop or done
-                or (step + 1) % self.loop.checkpoint_every == 0)
-            if save:
-                with self.tracer.span("checkpoint", step=step):
-                    self.ckpt.save(
-                        step + 1, self._pack(state, scale_state, err),
-                        extra={"straggler_ema": ema,
-                               "stragglers": stragglers})
+                done = step + 1 >= self.loop.total_steps
+                save = self.ckpt is not None and (
+                    self._stop or done
+                    or (step + 1) % self.loop.checkpoint_every == 0)
+                if save:
+                    with self.tracer.span("checkpoint"):
+                        self.ckpt.save(
+                            step + 1, self._pack(state, scale_state, err),
+                            extra={"straggler_ema": ema,
+                                   "stragglers": stragglers})
 
-            # Serialize first (scalar/vector-aware), then let the health
-            # detectors see the exact record, so events land ON the record
-            # whose metrics triggered them.
-            record = {k: jsonable(v) for k, v in metrics.items()}
-            record.update(step=step, step_time_s=round(dt, 4),
-                          stragglers=stragglers, **self._comm,
-                          **self.tracer.durations())
-            events = self.monitor.observe(step, record)
-            if events:
-                record["health_events"] = events
-            record = logger.log(record)
-            if self.on_metrics:
-                self.on_metrics(step, record)
+                with self.tracer.span("record"):
+                    # Serialize first (scalar/vector-aware), then let the
+                    # health detectors see the exact record, so events land
+                    # ON the record whose metrics triggered them. This
+                    # step's record and on_metrics spans are timed into the
+                    # next record.
+                    record = {k: jsonable(v) for k, v in metrics.items()}
+                    record.update(step=step, step_time_s=round(dt, 4),
+                                  stragglers=stragglers,
+                                  compiles=obs_trace.compiles(), **self._comm,
+                                  **self.tracer.durations())
+                    events = self.monitor.observe(step, record)
+                    if events:
+                        record["health_events"] = events
+                    record = logger.log(record)
+                if self.on_metrics:
+                    with self.tracer.span("on_metrics"):
+                        self.on_metrics(step, record)
             last_metrics = record
             if step % self.loop.log_every == 0:
                 # non-finite metrics serialize as strings ("inf"/"nan")
@@ -321,8 +336,40 @@ class TrainLoop:
                 if save:
                     print(f"[train] preempted: checkpointed at {step + 1}")
                 break
+        if batch is not None:
+            self._keep_step_args(state, scale_state, err, batch, step_key)
         if self.ckpt is not None:
             self.ckpt.wait()
         return {"state": state, "scale_state": scale_state,
                 "wire_error": err, "last_step": step + 1,
                 "metrics": last_metrics, "stragglers": stragglers}
+
+    def _keep_step_args(self, state, scale_state, err, batch, step_key):
+        """Note the step's operand shapes (the carried state as the last
+        step returned it: what the next call would take) for `step_text`,
+        and keep the step's text in `obs.trace` (`last_step_text`)."""
+        carried = (state,)
+        if self.scaling is not None:
+            carried += (scale_state,)
+        if self.wire:
+            carried += (err,)
+        self._step_args = jax.tree_util.tree_map(
+            _spec, carried + (batch, step_key))
+        obs_trace.keep_step(functools.partial(
+            _compiled_text, self._step_fn, self._step_args))
+
+
+def _spec(x) -> jax.ShapeDtypeStruct:
+    """A step operand as the call saw it. A one-device placement is left
+    out, as the call leaves it out of the program: the lowering is then the
+    call's own, and compiles to the executable the call runs."""
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    one = isinstance(x.sharding, jax.sharding.SingleDeviceSharding)
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, weak_type=x.weak_type,
+                                sharding=None if one else x.sharding)
+
+
+def _compiled_text(step_fn, args) -> str:
+    return step_fn.lower(*args).compile().as_text()

@@ -21,6 +21,7 @@ from repro.core.loss_scale import LossScaler
 from repro.core.master_weights import MixedPrecisionOptimizer, MixedPrecisionState
 from repro.models.config import ModelConfig
 from repro.models.transformer import encode, forward, lm_loss
+from repro.obs.trace import scope
 from repro.optim import make_optimizer
 from repro.scaling import context as scale_ctx
 from repro.scaling.context import AMAX_PREFIX, HEALTH_PREFIX
@@ -103,6 +104,7 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
             return lm_loss(params, batch, cfg=cfg, qkey=step_key,
                            loss_scale=scale)
 
+    @scope("train.grads")
     def _grads_and_metrics(params, batch, step_key, scale, scale_state,
                            constrain=None):
         constrain = constrain_grads if constrain is None else constrain
@@ -188,17 +190,18 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                 loss, metrics, grads, tok_grads = _grads_and_metrics(
                     params_, batch_, key_, scale_, sstate_,
                     constrain=lambda g: g)
-            if inner or reduce_all:
-                grads = jax.tree_util.tree_map(
-                    lambda g: jax.lax.pmean(g, dp if reduce_all else inner),
-                    grads)
-            loss = jax.lax.pmean(loss, dp)
-            metrics = {k: (jax.lax.pmax(v, dp)
-                           if k.startswith((AMAX_PREFIX, HEALTH_PREFIX))
-                           else jax.lax.pmean(v, dp))
-                       for k, v in metrics.items()}
-            tok_grads = {k: _combine_tokens(v, dp)
-                         for k, v in tok_grads.items()}
+            with scope("train.allreduce"):
+                if inner or reduce_all:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: jax.lax.pmean(
+                            g, dp if reduce_all else inner), grads)
+                loss = jax.lax.pmean(loss, dp)
+                metrics = {k: (jax.lax.pmax(v, dp)
+                               if k.startswith((AMAX_PREFIX, HEALTH_PREFIX))
+                               else jax.lax.pmean(v, dp))
+                           for k, v in metrics.items()}
+                tok_grads = {k: _combine_tokens(v, dp)
+                             for k, v in tok_grads.items()}
             if not reduce_all:
                 grads = jax.tree_util.tree_map(
                     lambda g: g.astype(jnp.float32)[None], grads)
@@ -219,10 +222,19 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
             return _grads_and_metrics(params, batch, step_key, scale,
                                       scale_state)
         loss, metrics, grads, tok_grads = _wire_grads_and_metrics(
-            plan.gather_params(params), batch, step_key, scale, scale_state,
+            _gather(params), batch, step_key, scale, scale_state,
             reduce_all=True)
         return loss, metrics, constrain_grads(grads), tok_grads
 
+    @scope("train.allreduce")
+    def _gather(params):
+        return plan.gather_params(params)
+
+    @scope("train.optimizer")
+    def _compute_params(state):
+        return optimizer.compute_params(state)
+
+    @scope("train.optimizer")
     def _finish(state, grads, loss, metrics, scale):
         new_state, opt_metrics = optimizer.apply_gradients(state, grads)
         inv = 1.0 / jnp.maximum(scale, 1e-9)
@@ -231,9 +243,25 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
                **{k: v for k, v in metrics.items()}, **opt_metrics}
         return new_state, out
 
+    @scope("train.scaling")
+    def _update_scales(scale_state, metrics, tok_grads, sync):
+        """The history update from this step's observations; under
+        track_health also the scale-churn rate (fraction of registry rows
+        whose derived scale moved this step) and the dense freshest-amax
+        vector (registry row order — the logger meta carries the matching
+        site list) for the stuck/NaN-amax detectors."""
+        observed = split_observations(metrics, tok_grads, scaling.registry)
+        new = scaling.update(scale_state, observed, sync=sync)
+        health = {}
+        if scaling.qcfg.track_health:
+            health["health/scale_churn"] = jnp.mean(
+                (scale_state.scale != new.scale).astype(jnp.float32))
+            health["health/amax_sites"] = new.amax_history[:, 0]
+        return new, health
+
     def train_step(state: MixedPrecisionState, batch: Dict[str, Array],
                    step_key: Array) -> Tuple[MixedPrecisionState, Dict]:
-        params = optimizer.compute_params(state)
+        params = _compute_params(state)
         scale = state.loss_scale.scale
         loss, metrics, grads, _ = _local_grads(
             params, batch, step_key, scale, None)
@@ -241,34 +269,26 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
 
     def train_step_scaled(state: MixedPrecisionState, scale_state: ScaleState,
                           batch: Dict[str, Array], step_key: Array):
-        params = optimizer.compute_params(state)
+        params = _compute_params(state)
         scale = state.loss_scale.scale
         loss, metrics, grads, tok_grads = _local_grads(
             params, batch, step_key, scale, scale_state)
-        observed = split_observations(metrics, tok_grads, scaling.registry)
         # (manual_dp observations are already pmax-combined in the body.)
-        new_scale_state = scaling.update(
-            scale_state, observed, sync=None if manual_dp else amax_sync)
+        new_scale_state, health = _update_scales(
+            scale_state, metrics, tok_grads,
+            None if manual_dp else amax_sync)
         new_state, out = _finish(state, grads, loss, metrics, scale)
-        if scaling.qcfg.track_health:
-            # Scale-churn rate: fraction of registry rows whose derived
-            # scale moved this step; plus the dense freshest-amax vector
-            # (registry row order — the logger meta carries the matching
-            # site list) for the stuck/NaN-amax detectors.
-            out["health/scale_churn"] = jnp.mean(
-                (scale_state.scale != new_scale_state.scale)
-                .astype(jnp.float32))
-            out["health/amax_sites"] = new_scale_state.amax_history[:, 0]
-        return (new_state, new_scale_state), out
+        return (new_state, new_scale_state), {**out, **health}
 
     def train_step_wire(state: MixedPrecisionState, err,
                         batch: Dict[str, Array], step_key: Array):
-        params = optimizer.compute_params(state)
-        params = plan.gather_params(params)
+        params = _compute_params(state)
+        params = _gather(params)
         scale = state.loss_scale.scale
         loss, metrics, stacked, _ = _wire_grads_and_metrics(
             params, batch, step_key, scale, None)
-        reduced, new_err = plan.dp_allreduce()(stacked, err)
+        with scope("train.allreduce"):
+            reduced, new_err = plan.dp_allreduce()(stacked, err)
         new_state, out = _finish(state, constrain_grads(reduced),
                                  loss, metrics, scale)
         return (new_state, new_err), out
@@ -276,24 +296,20 @@ def make_train_step(cfg: ModelConfig, optimizer: MixedPrecisionOptimizer, *,
     def train_step_wire_scaled(state: MixedPrecisionState,
                                scale_state: ScaleState, err,
                                batch: Dict[str, Array], step_key: Array):
-        params = optimizer.compute_params(state)
-        params = plan.gather_params(params)
+        params = _compute_params(state)
+        params = _gather(params)
         scale = state.loss_scale.scale
         loss, metrics, stacked, tok_grads = _wire_grads_and_metrics(
             params, batch, step_key, scale, scale_state)
-        reduced, new_err = plan.dp_allreduce()(stacked, err)
-        observed = split_observations(metrics, tok_grads, scaling.registry)
+        with scope("train.allreduce"):
+            reduced, new_err = plan.dp_allreduce()(stacked, err)
         # No amax_sync here: observations were pmax-combined across devices
         # inside the shard_map body already.
-        new_scale_state = scaling.update(scale_state, observed, sync=None)
+        new_scale_state, health = _update_scales(
+            scale_state, metrics, tok_grads, None)
         new_state, out = _finish(state, constrain_grads(reduced),
                                  loss, metrics, scale)
-        if scaling.qcfg.track_health:
-            out["health/scale_churn"] = jnp.mean(
-                (scale_state.scale != new_scale_state.scale)
-                .astype(jnp.float32))
-            out["health/amax_sites"] = new_scale_state.amax_history[:, 0]
-        return (new_state, new_scale_state, new_err), out
+        return (new_state, new_scale_state, new_err), {**out, **health}
 
     if wire:
         return train_step_wire if scaling is None else train_step_wire_scaled

@@ -10,7 +10,9 @@ healthdash rendering + schema validation, and straggler-EMA persistence
 across checkpoint restarts.
 """
 import dataclasses
+import glob
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +23,8 @@ from repro.core.loss_scale import LossScaler
 from repro.core.precision_policy import QuantConfig
 from repro.obs.health import HealthConfig, HealthMonitor
 from repro.obs.metrics import SCHEMA_VERSION, MetricsLogger, jsonable
-from repro.obs.trace import Tracer
+from repro.obs import trace as obs_trace
+from repro.obs.trace import SCOPES, Tracer, op_scopes
 from repro.scaling import context as sc
 from repro.scaling.state import DelayedScaling, SiteRegistry
 from repro.tools import healthdash
@@ -90,23 +93,189 @@ class TestMetricsLogger:
         logger.close()
 
 
+def profiled_host_spans(path):
+    """Names of the host events of the newest profiler trace under `path`."""
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(str(path), "**", "*.xplane.pb"),
+                             recursive=True))
+    assert files, f"no profiler trace under {path}"
+    data = ProfileData.from_file(files[-1])
+    return [ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
 class TestTracer:
     def test_spans_and_export(self, tmp_path):
-        path = str(tmp_path / "trace.json")
-        tr = Tracer(path)
-        with tr.span("data_wait", step=0):
-            pass
-        with tr.span("step_dispatch", step=0):
-            pass
+        tr = Tracer("repro.train")
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("data_wait", step=0):
+                pass
+            with tr.span("step_dispatch"):
+                pass
         d = tr.durations()
         assert set(d) == {"span/data_wait_s", "span/step_dispatch_s"}
         assert all(v >= 0 for v in d.values())
         assert tr.durations() == {}  # popped
-        tr.export()
-        trace = json.loads(open(path).read())
-        evs = trace["traceEvents"]
-        assert {e["name"] for e in evs} == {"data_wait", "step_dispatch"}
-        assert all(e["ph"] == "X" for e in evs)
+        names = profiled_host_spans(tmp_path)
+        assert "repro.train.data_wait" in names
+        assert "repro.train.step_dispatch" in names
+
+    def test_spans_time_without_a_trace(self):
+        tr = Tracer("repro.serve")
+        for _ in range(3):
+            with tr.span("decode", active=2):
+                pass
+        d = tr.durations()
+        assert list(d) == ["span/decode_s"] and d["span/decode_s"] >= 0
+
+
+# ---------------------------------------------------------------------------
+# device scopes: op_scopes over compiled text
+# ---------------------------------------------------------------------------
+
+HAND_HLO = """HloModule jit_step, is_scheduled=true
+
+%fused_computation (param_0: f32[8]) -> u8[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %xor.3 = u8[8]{0} xor(%param_0), metadata={op_name="jit(step)/train.grads/jvp()/fp8.quant/fp8.sr_bits/xor"}
+}
+
+%body (arg: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %arg = (s32[], f32[8]{0}) parameter(0)
+  %gte.1 = f32[8]{0} get-tuple-element(%arg), index=1
+  %xor_convert_fusion.2 = u8[8]{0} fusion(%gte.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/train.grads/jvp()/while/body/fp8.quant/fp8.sr_bits/xor"}
+  %convert.5 = f32[8]{0} convert(%gte.1), metadata={op_name="jit(step)/train.grads/transpose(jvp(fp8.quant))/convert_element_type"}
+  %and.7 = u8[8]{0} and(%xor_convert_fusion.2), metadata={op_name="jit(step)/train.grads/transpose(jvp())/while/body/fp8.amax/and"}
+  ROOT %tuple.1 = (s32[], f32[8]{0}) tuple(%gte.1, %convert.5)
+}
+
+ENTRY %main (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0), metadata={op_name="p"}
+  %while.24 = (s32[], f32[8]{0}) while(%p), body=%body, metadata={op_name="jit(step)/train.grads/jvp()/while"}
+  %multiply.9 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/train.optimizer/mul" source_file="x.py" source_line=3}
+  ROOT %copy.1 = f32[8]{0} copy(%p)
+}
+"""
+
+
+class TestOpScopes:
+    def test_innermost_scope_across_computations(self):
+        m = op_scopes(HAND_HLO)
+        # nested scopes: the innermost wins, in a fusion and in its body
+        assert m["xor_convert_fusion.2"] == "fp8.sr_bits"
+        assert m["xor.3"] == "fp8.sr_bits"
+        # a backward op carries its forward scope inside the transform
+        assert m["convert.5"] == "fp8.quant"
+        assert m["and.7"] == "fp8.amax"
+        # the while op itself and the entry computation
+        assert m["while.24"] == "train.grads"
+        assert m["multiply.9"] == "train.optimizer"
+        # no scope on the path, or no metadata: left out
+        assert "p" not in m and "copy.1" not in m and "gte.1" not in m
+
+    def test_scope_rejects_names_outside_the_vocabulary(self):
+        with pytest.raises(ValueError):
+            obs_trace.scope("fp8.other")
+
+    def test_scopes_only_add_metadata(self):
+        """A scope changes no computed bit and no op of the compiled
+        program: only the metadata differs."""
+        def f(x, scoped):
+            if scoped:
+                with obs_trace.scope("fp8.quant"):
+                    return (x * 3.0).astype(jnp.float8_e5m2)
+            return (x * 3.0).astype(jnp.float8_e5m2)
+        x = jnp.linspace(-9.0, 9.0, 64)
+        texts = {}
+        for scoped in (False, True):
+            fn = jax.jit(lambda x, s=scoped: f(x, s))
+            texts[scoped] = fn.lower(x).compile().as_text()
+            np.testing.assert_array_equal(
+                np.asarray(fn(x)).view(np.uint8),
+                np.asarray(f(x, False)).view(np.uint8))
+
+        def ops(text):
+            return [ln.split(", metadata=")[0] for ln in text.splitlines()
+                    if " = " in ln and "parameter(" not in ln]
+        assert ops(texts[True]) == ops(texts[False])
+        assert set(op_scopes(texts[True]).values()) == {"fp8.quant"}
+        assert op_scopes(texts[False]) == {}
+
+
+def _tiny_loop():
+    """The benchmark cell's program path (hybrid formats, delayed scaling)
+    on the XLA FP8 backend, at a size the CPU compiles in seconds."""
+    from repro.launch.train import make_train_loop, train_config
+    cfg, _ = train_config("qwen2-1.5b", overrides=[
+        "policy.quant.backend=xla", "policy.quant.recipe=hybrid",
+        "policy.quant.scaling=delayed"])
+    cfg = cfg.replace(n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+                      d_ff=128, vocab_size=512)
+    return make_train_loop(cfg, steps=1, batch=2, seq=64, seed=3,
+                           log_every=100)
+
+
+@pytest.fixture(scope="module")
+def tiny_step_scopes():
+    loop = _tiny_loop()
+    loop.run()
+    text = loop.step_text()
+    assert obs_trace.last_step_text() == text
+    return op_scopes(text)
+
+
+@pytest.mark.parametrize("name", [s for s in SCOPES
+                                  if s != "train.allreduce"])
+def test_each_scope_names_an_instruction_of_the_train_step(
+        tiny_step_scopes, name):
+    """Every phase of the vocabulary is found in the compiled one-device
+    train step (the data-parallel reduction exists only on a wire path)."""
+    assert name in set(tiny_step_scopes.values())
+
+
+def test_fused_gemm_sr_draw_and_amax_are_scoped():
+    """The fused GEMM's SR bits, drawn outside the kernel, and its amax
+    reduction over the per-tile outputs, lowered in interpret mode."""
+    from repro.kernels.fused_quant_matmul import ops as fq_ops
+    a = jnp.ones((16, 128), jnp.float8_e4m3fn)
+    b = jnp.ones((128, 256), jnp.float8_e4m3fn)
+    fn = jax.jit(lambda a, b, k: fq_ops.fused_quant_matmul(
+        a, b, k, dims="nn", bm=8, bk=128, bn=128, rounding="sr",
+        with_amax=True, interpret=True))
+    text = fn.lower(a, b, jax.random.PRNGKey(0)).compile().as_text()
+    found = set(op_scopes(text).values())
+    assert {"fp8.sr_bits", "fp8.amax"} <= found
+
+
+# ---------------------------------------------------------------------------
+# compile counters
+# ---------------------------------------------------------------------------
+
+def test_compiles_rise_on_a_retrace_not_on_a_repeat_call():
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    x3, x5 = np.ones((3,), np.float32), np.ones((5,), np.float32)
+    f(x3).block_until_ready()
+    n, sec = obs_trace.compiles(), obs_trace.compile_seconds()
+    f(x3).block_until_ready()
+    assert obs_trace.compiles() == n
+    f(x5).block_until_ready()     # a new shape retraces
+    assert obs_trace.compiles() == n + 1
+    after = obs_trace.compile_seconds()
+    assert after["compile/backend_s"] > sec["compile/backend_s"]
+    assert after["compile/trace_s"] > sec["compile/trace_s"]
+
+
+def test_nested_compile_events_count_once():
+    sp = obs_trace._Spans()
+    sp.add(2.0, 3.0)          # an inner jit, traced inside the outer one
+    sp.add(5.0, 6.0)
+    sp.add(1.0, 7.0)          # the outer trace, reported when it ends
+    sp.add(10.0, 11.0)
+    assert sp.seconds() == pytest.approx(7.0)
+    assert sp.seconds(until=10.5) == pytest.approx(6.5)
+    sp.add(6.5, 10.2)         # overlaps both: merges them
+    assert sp.seconds() == pytest.approx(10.0)
 
 
 # ---------------------------------------------------------------------------
@@ -419,7 +588,7 @@ def test_forced_saturation_emits_event_and_renders():
 # ---------------------------------------------------------------------------
 
 def _loop(tmp_path, total_steps, *, init_scale, metrics=None,
-          n_microbatches=1, mode="dynamic"):
+          n_microbatches=1, mode="dynamic", on_metrics=None, trace=False):
     from repro.data import DataConfig, synthetic_lm_batches
     from repro.models.registry import build_config
     from repro.train.loop import LoopConfig, TrainLoop
@@ -435,8 +604,8 @@ def _loop(tmp_path, total_steps, *, init_scale, metrics=None,
     loop = LoopConfig(total_steps=total_steps, checkpoint_every=5,
                       checkpoint_dir=str(tmp_path / "ckpt"), log_every=100,
                       metrics_path=metrics, n_microbatches=n_microbatches,
-                      trace_path=str(tmp_path / "trace.json"))
-    return TrainLoop(cfg, opt, data, loop, seed=0)
+                      trace_path=str(tmp_path / "trace") if trace else None)
+    return TrainLoop(cfg, opt, data, loop, seed=0, on_metrics=on_metrics)
 
 
 def test_forced_overflow_counts_once_and_emits(tmp_path):
@@ -446,7 +615,8 @@ def test_forced_overflow_counts_once_and_emits(tmp_path):
     validates, and healthdash renders it."""
     mpath = str(tmp_path / "m.jsonl")
     _loop(tmp_path, 6, init_scale=2.0 ** 127, metrics=mpath,
-          n_microbatches=2).run()
+          n_microbatches=2, on_metrics=lambda step, rec: None,
+          trace=True).run()
     records, meta = healthdash.load_metrics(mpath)
     assert len(records) == 6
     # step 0 overflowed exactly once despite 2 microbatches
@@ -455,14 +625,27 @@ def test_forced_overflow_counts_once_and_emits(tmp_path):
     assert counts == sorted(counts)
     events = [e for r in records for e in r.get("health_events", [])]
     assert any(e["kind"] == "overflow" for e in events)
-    # spans made it into the records
+    # spans made it into the records (a step's record and on_metrics
+    # spans land in the next record), with the compile counter
     assert all("span/step_dispatch_s" in r for r in records)
+    assert all("span/record_s" in r and "span/on_metrics_s" in r
+               for r in records[1:])
+    compiles = [r["compiles"] for r in records]
+    assert compiles == sorted(compiles) and compiles[0] >= 1
     assert healthdash.validate_records(records, meta) == []
     md = healthdash.render(records, meta)
     assert "overflow" in md
-    # trace exported alongside
-    trace = json.loads((tmp_path / "trace.json").read_text())
-    assert trace["traceEvents"]
+    # a profiler trace recorded alongside: every step's phases on the
+    # profiler's clock, inside the step annotation, and a perfetto trace
+    names = profiled_host_spans(tmp_path / "trace")
+    for phase in ("data_wait", "step_dispatch", "device_sync", "record",
+                  "on_metrics", "checkpoint"):
+        assert f"repro.train.{phase}" in names, phase
+    assert names.count("repro.train.step_dispatch") == 6
+    assert names.count("train") == 6
+    assert "repro.setup.init_state" in names
+    assert glob.glob(str(tmp_path / "trace" / "**" /
+                         "perfetto_trace.json.gz"), recursive=True)
 
 
 def test_quant_loop_vector_metrics_and_schema(tmp_path):
