@@ -314,8 +314,11 @@ def vmem_fit_pass(cfg, meta, cell: str) -> List[Finding]:
                                  "tn")):
             bm, bk, bn = at.resolve_gemm_blocks(
                 dims, m, k, n, autotune=q.autotune, defaults=defaults)
-            est = vm.gemm_vmem(min(bm, max(8, m)), min(bk, max(128, k)),
-                               min(bn, max(128, n)), dims=dims)
+            bm, bk, bn = (min(bm, max(8, m)), min(bk, max(128, k)),
+                          min(bn, max(128, n)))
+            a_bytes, b_bytes = vm.gemm_operand_bytes(m, n, bm, bn)
+            est = vm.gemm_vmem(bm, bk, bn, dims=dims, a_bytes=a_bytes,
+                               b_bytes=b_bytes)
             if not est.fits:
                 findings.append(Finding(
                     "vmem_fit", "error", cell,

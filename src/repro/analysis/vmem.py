@@ -80,16 +80,26 @@ class VmemEstimate:
 
 
 # -------------------------------------------------------------- fused GEMM
+def gemm_operand_bytes(m: int, n: int, bm: int, bn: int) -> Tuple[int, int]:
+    """(a_bytes, b_bytes): element width of the fused GEMM's operand blocks
+    for a logical (m, n) output at (bm, bn) tiles — 2 for an operand the ops
+    layer widens to bf16 (`kernel.widened_operands`), 1 for fp8."""
+    from repro.kernels.fused_quant_matmul.kernel import widened_operands
+    wa, wb = widened_operands(m, n, bm, bn)
+    return (2 if wa else 1), (2 if wb else 1)
+
+
 def gemm_vmem(bm: int, bk: int, bn: int, *, dims: str = "nn",
-              with_amax: bool = True,
+              with_amax: bool = True, a_bytes: int = 1, b_bytes: int = 1,
               budget: Optional[int] = None) -> VmemEstimate:
     """Fused quantize-epilogue GEMM (and the plain fp8_matmul, whose
-    working set is a strict subset): fp8 a/b blocks + u8 SR-bits block in,
-    fp8 out block + the amax/health stats block out, one (bm, bn) f32
-    accumulator scratch.  Layout transposes (nn/nt/tn) permute block
-    dims, not bytes."""
-    a_blk = bm * bk                       # fp8, 1 byte
-    b_blk = bk * bn
+    working set is a strict subset): a/b blocks at their own element
+    width (`a_bytes`/`b_bytes`: 1 for fp8, 2 for bf16; see
+    `gemm_operand_bytes`) + u8 SR-bits block in, fp8 out block + the
+    amax/health stats block out, one (bm, bn) f32 accumulator scratch.
+    Layout transposes (nn/nt/tn) permute block dims, not bytes."""
+    a_blk = bm * bk * a_bytes
+    b_blk = bk * bn * b_bytes
     rand_blk = bm * bn                    # uint8 SR bits
     out_blk = bm * bn                     # fp8 payload
     tiles = STATS_TILE_BYTES if with_amax else 0   # counts share it
@@ -98,7 +108,9 @@ def gemm_vmem(bm: int, bk: int, bn: int, *, dims: str = "nn",
         "out_blocks_x2": DMA_BUF * (out_blk + tiles),
         "acc_scratch_f32": bm * bn * 4,
     }
-    return VmemEstimate("fused_gemm", {"bm": bm, "bk": bk, "bn": bn},
+    return VmemEstimate("fused_gemm", {"bm": bm, "bk": bk, "bn": bn,
+                                       "a_bytes": a_bytes,
+                                       "b_bytes": b_bytes},
                         parts, _budget(budget))
 
 
@@ -216,14 +228,20 @@ def check_gemm_blocks(bm: int, bk: int, bn: int, *, dims: str = "nn",
 
 # ----------------------------------------------------------------- pruning
 def prune_gemm_candidates(cands: Sequence[Tuple[int, int, int]], *,
-                          dims: str = "nn", budget: Optional[int] = None
+                          dims: str = "nn",
+                          mn: Optional[Tuple[int, int]] = None,
+                          budget: Optional[int] = None
                           ) -> Tuple[list, List[dict]]:
-    """Split GEMM sweep candidates into (kept, pruned).  `pruned` entries
-    carry the modeled footprint so the sweep can record WHAT it skipped
-    and WHY (no silent caps)."""
+    """Split GEMM sweep candidates into (kept, pruned).  With the logical
+    output `mn`, each candidate's operand blocks are counted at the width
+    the ops layer gives them (`gemm_operand_bytes`); without it, as fp8.
+    `pruned` entries carry the modeled footprint so the sweep can record
+    WHAT it skipped and WHY (no silent caps)."""
     kept, pruned = [], []
     for c in cands:
-        est = gemm_vmem(*c, dims=dims, budget=budget)
+        widths = gemm_operand_bytes(*mn, c[0], c[2]) if mn else (1, 1)
+        est = gemm_vmem(*c, dims=dims, a_bytes=widths[0],
+                        b_bytes=widths[1], budget=budget)
         if est.fits:
             kept.append(c)
         else:

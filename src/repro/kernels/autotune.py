@@ -27,6 +27,12 @@ at s=256).  This module closes that gap:
    streamed-invariance law), so a stale or foreign table can only change
    speed, never bits.
 
+Entries that carry a `device` key were timed on that chip with the real
+fused kernel in the form the ops layer runs it (operands widened per
+`fused_quant_matmul.kernel.widened_operands`), against the fp8 operands at
+the built-in defaults (`default_wall_us`), with bk held at the default so
+the output bits match it; the others come from the CPU analogue sweep.
+
 Table location: `src/repro/kernels/autotune_table.json` (shipped with the
 repo), overridable via `$REPRO_AUTOTUNE_TABLE`.  The `autotune` knob on
 the ops (and `QuantConfig.autotune`) is `"table"` (consult the default
@@ -588,8 +594,11 @@ def sweep_gemm(shapes=None, *, dims_list=("nn", "nt", "tn"),
             # — it anchors tuned_vs_default — but gets a loud warning if
             # the model says it wouldn't fit either.
             from repro.analysis import vmem as _vm
-            kept, pruned = _vm.prune_gemm_candidates(cands[1:], dims=dims)
-            if not _vm.gemm_vmem(*cands[0], dims=dims).fits:
+            kept, pruned = _vm.prune_gemm_candidates(cands[1:], dims=dims,
+                                                     mn=(m, n))
+            widths = _vm.gemm_operand_bytes(m, n, cands[0][0], cands[0][2])
+            if not _vm.gemm_vmem(*cands[0], dims=dims, a_bytes=widths[0],
+                                 b_bytes=widths[1]).fits:
                 log(f"[autotune] WARNING: default GEMM blocks "
                     f"{cands[0]} exceed the VMEM model for "
                     f"({m}, {k}, {n}) {dims}; timing it anyway as the "

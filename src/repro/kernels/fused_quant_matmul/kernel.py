@@ -29,6 +29,15 @@ The optional amax epilogue output is reported in *grid units* (the max |q|
 of the quantized fp8 values, before de-scaling) and masked to the logical
 (m, n) region, so zero-padded tiles can never leak into the delayed-scaling
 observation.
+
+Operands hold fp8 values but may arrive in either dtype, each on its own:
+fp8 (1 byte; the tile is widened to bf16 in VMEM on every k step) or bf16
+already widened by the caller (2 bytes; no per-step cast). bf16 holds every
+e4m3 and e5m2 value exactly, so the products, the f32 accumulator and every
+output are bit-identical either way. `ops.fused_quant_matmul` widens an
+operand in HBM when the grid visits each of its tiles more than once
+(`widened_operands`); v5e has no FP8 unit, so the per-step cast is VPU work
+that would otherwise be repeated on every visit.
 """
 from __future__ import annotations
 
@@ -63,7 +72,8 @@ def _quantize_tile(acc, rand8, inv_scale, *, fmt_name: str, rounding: str,
 
 
 def _tile_dot(a, b, dims: str):
-    """f32-accumulated bf16 tile contraction for one k step of `dims`."""
+    """f32-accumulated bf16 tile contraction for one k step of `dims`; the
+    cast is a no-op for an operand that arrives in bf16."""
     a = a.astype(jnp.bfloat16)
     b = b.astype(jnp.bfloat16)
     if dims == "nn":      # (bm, bk) x (bk, bn)
@@ -76,14 +86,13 @@ def _tile_dot(a, b, dims: str):
                                preferred_element_type=jnp.float32)
 
 
-def _amax_mask(bm: int, bn: int, m: int, n: int):
-    """Validity mask of the current (bm, bn) output tile against the logical
-    (m, n) bounds — padded rows/cols are excluded from the amax epilogue so
-    the observation is invariant to the (bm, bk, bn) tiling choice."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0) \
-        + pl.program_id(0) * bm
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) \
-        + pl.program_id(1) * bn
+def _amax_mask(i, j, bm: int, bn: int, m: int, n: int):
+    """Validity mask of output tile (i, j) of shape (bm, bn) against the
+    logical (m, n) bounds — padded rows/cols are excluded from the amax
+    epilogue so the observation is invariant to the (bm, bk, bn) tiling
+    choice."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 0) + i * bm
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + j * bn
     return (rows < m) & (cols < n)
 
 
@@ -119,9 +128,10 @@ def _body(a_ref, b_ref, rand_ref, scale_ref, o_ref, *refs,
 
     acc_ref[...] += _tile_dot(a_ref[...], b_ref[...], dims)
 
-    # Built at body top level: interpret mode does not substitute
-    # program_id inside pl.when sub-jaxprs, so the epilogue closes over it.
-    mask = _amax_mask(*acc_ref.shape, m, n) if with_amax else None
+    # Read at body top level: interpret mode does not substitute
+    # program_id inside pl.when sub-jaxprs, so the epilogue closes over
+    # the indices and builds the mask on the last k step only.
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
@@ -132,6 +142,7 @@ def _body(a_ref, b_ref, rand_ref, scale_ref, o_ref, *refs,
         o_ref[...] = q
         if stats_ref is None:
             return
+        mask = _amax_mask(i, j, *acc_ref.shape, m, n)
         qf = q.astype(jnp.float32)
         row = jax.lax.broadcasted_iota(jnp.int32, stats_ref.shape, 0)
         stats = jnp.where(row == 0,
@@ -174,6 +185,17 @@ def gemm_shape(a_shape, b_shape, dims: str):
     return m, n, c
 
 
+def widened_operands(m: int, n: int, bm: int, bn: int):
+    """(widen_a, widen_b): which operands the caller widens to bf16 in HBM
+    before the call. The (i, j, k) grid reads each A tile once per output
+    column block, cdiv(n, bn) times, and each B tile once per output row
+    block, cdiv(m, bm) times, in every layout. An operand read more than
+    once is widened in one pass rather than cast again on every visit; one
+    read once (a decode call's weight, M <= bm) stays fp8 and is cast in
+    the kernel, which costs no extra pass over it."""
+    return pl.cdiv(n, bn) > 1, pl.cdiv(m, bm) > 1
+
+
 def fused_quant_matmul_kernel(a, b, rand8, scale, *,
                               dims: str = "nn",
                               bm=DEFAULT_BM, bk=DEFAULT_BK, bn=DEFAULT_BN,
@@ -185,6 +207,7 @@ def fused_quant_matmul_kernel(a, b, rand8, scale, *,
                               interpret: bool = False):
     """fp8 GEMM (layout per `dims`, see module docstring) with the Q node in
     the epilogue: out = Q((a . b) / scale) -> (M, N) fp8 in `out_format`.
+    a, b: fp8, or bf16 holding fp8 values (each operand on its own).
     rand8: (M, N) u8 SR bits, scale: (1,) f32.
 
     with_amax=True additionally returns a (grid_m, grid_n) f32 array of

@@ -1,4 +1,15 @@
-"""Jit'd public wrapper for fused_quant_matmul."""
+"""Jit'd public wrapper for fused_quant_matmul.
+
+The wrapper hands the kernel each operand either as the fp8 payload or
+widened to bf16 by one XLA convert (exact: bf16 holds every e4m3 and e5m2
+value). The rule is `kernel.widened_operands` on the resolved blocks: an
+operand whose tiles the grid reads more than once is widened, since the
+kernel would otherwise cast the same fp8 tile to bf16 on every visit (v5e
+has no FP8 unit, so that cast is VPU work on the k loop). An operand read
+once, such as a decode GEMM's weight (M <= bm), stays fp8. The convert
+runs under the `fp8.quant` scope; the caller's fp8 payloads (the custom-VJP
+residuals) are untouched.
+"""
 from __future__ import annotations
 
 import functools
@@ -52,6 +63,9 @@ def fused_quant_matmul(a, b, key, scale=None, *,
     alongside the operands, and the amax epilogue masks the padded region, so
     results are invariant to the (bm, bk, bn) tiling choice.
 
+    a and b are fp8 payloads; each one whose tiles the kernel grid reads
+    more than once enters the kernel widened to bf16 (module docstring).
+
     bm/bk/bn default to None: unset knobs resolve through the block-size
     autotuner winners table (`autotune`: "table" = the shipped /
     $REPRO_AUTOTUNE_TABLE table, "off" = built-in defaults, or a table
@@ -82,6 +96,16 @@ def fused_quant_matmul(a, b, key, scale=None, *,
         ap, bp = _pad_to(a, bm_, bk_), _pad_to(b, bn_, bk_)
     else:  # "tn"
         ap, bp = _pad_to(a, bk_, bm_), _pad_to(b, bk_, bn_)
+    widen_a, widen_b = _k.widened_operands(m, n, bm_, bn_)
+    with scope("fp8.quant"):
+        # The barrier keeps each convert a pass of its own. Fused into the
+        # producer of the payload, it changes how XLA compiles that
+        # producer on the TPU, and the training step's bits drift from
+        # the unwidened program's; kept apart, they match bit for bit.
+        if widen_a:
+            ap = jax.lax.optimization_barrier(ap).astype(jnp.bfloat16)
+        if widen_b:
+            bp = jax.lax.optimization_barrier(bp).astype(jnp.bfloat16)
     # Draw SR bits for the logical cells only; padded cells get zero bits
     # (their zero accumulator then stays exactly zero under SR truncation).
     with scope("fp8.sr_bits"):

@@ -14,6 +14,7 @@ delayed scaling):
      are invariant to the (bm, bk, bn) tiling choice.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -301,3 +302,100 @@ class TestTilingInvariance:
                                      rounding="sr")
         np.testing.assert_array_equal(np.asarray(y).view(np.uint8),
                                       np.asarray(ref).view(np.uint8))
+
+
+# ---------------------------------------------------------------------------
+# operand widening: bf16 operands on the fp8 grid, and the shape rule
+# ---------------------------------------------------------------------------
+
+def _special_payload(shape, fmt_name, key):
+    """fp8 payload of random values with the format's edge cases planted:
+    ±min_subnormal, a mid subnormal, ±max_normal, NaN and (e5m2) ±inf."""
+    from repro.core.fp8_formats import get_format
+    fmt = get_format(fmt_name)
+    x = jax.random.normal(key, shape, jnp.float32) * 0.5
+    specials = [fmt.min_subnormal, -fmt.min_subnormal,
+                3 * fmt.min_subnormal, fmt.max_normal, -fmt.max_normal,
+                float("nan")]
+    if fmt_name == "e5m2":
+        specials += [float("inf"), float("-inf")]
+    rows = np.arange(len(specials)) * 3 % shape[0]
+    cols = np.arange(len(specials)) * 7 % shape[1]
+    x = x.at[rows, cols].set(jnp.array(specials, jnp.float32))
+    return x.astype(fmt.dtype)
+
+
+class TestOperandWidening:
+    # (m, k, n) logical GEMM on a (2, 2, 2) grid at (32, 128, 128) tiles.
+    M, K, N = 64, 256, 256
+    SHAPES = {"nn": ((M, K), (K, N)), "nt": ((M, K), (N, K)),
+              "tn": ((K, M), (K, N))}
+
+    @pytest.mark.parametrize("widen", ["neither", "a", "b", "both"])
+    @pytest.mark.parametrize("fmt", ["e4m3", "e5m2"])
+    @pytest.mark.parametrize("dims", ["nn", "nt", "tn"])
+    def test_bf16_operands_bit_match_fp8(self, dims, fmt, widen):
+        """An operand handed to the kernel widened to bf16 gives the same
+        output bytes, grid amax and health counts as the fp8 payload:
+        bf16 holds every fp8 value (subnormals, ±max, inf, NaN)."""
+        from repro.kernels.fused_quant_matmul import kernel as fk
+        ash, bsh = self.SHAPES[dims]
+        a8 = _special_payload(ash, fmt, jax.random.PRNGKey(0))
+        b8 = _special_payload(bsh, fmt, jax.random.PRNGKey(1))
+        rand8 = jax.random.bits(jax.random.PRNGKey(2), (self.M, self.N),
+                                jnp.uint8)
+        scale = jnp.array([4.0], jnp.float32)
+
+        def run(a, b):
+            return fk.fused_quant_matmul_kernel(
+                a, b, rand8, scale, dims=dims, bm=32, bk=128, bn=128,
+                out_format="e5m2", rounding="sr", saturate=False,
+                with_amax=True, with_counts=True, interpret=True)
+
+        a = a8.astype(jnp.bfloat16) if widen in ("a", "both") else a8
+        b = b8.astype(jnp.bfloat16) if widen in ("b", "both") else b8
+        got, want = run(a, b), run(a8, b8)
+        q, q0 = np.asarray(got[0]), np.asarray(want[0])
+        # The edge cases reach the output: NaN rows, and inf from e5m2 inf.
+        assert np.isnan(q0.astype(np.float32)).any()
+        assert np.isinf(q0.astype(np.float32)).any() == (fmt == "e5m2")
+        np.testing.assert_array_equal(q.view(np.uint8), q0.view(np.uint8))
+        for g, w in zip(got[1:], want[1:]):            # amax, sat, flush
+            np.testing.assert_array_equal(
+                np.asarray(g).view(np.uint32), np.asarray(w).view(np.uint32))
+
+    @pytest.mark.parametrize("case,dims,ash,bsh,want", [
+        # training: A read once per column block, B once per row block
+        ("train", "nn", (4096, 1536), (1536, 8960), (True, True)),
+        # decode: M = 8 <= bm, so the weight is read once and stays fp8
+        ("decode", "nn", (8, 1536), (1536, 8960), (True, False)),
+        # k/v projection: N = 256 = bn, so A is read once and stays fp8
+        ("kv", "nn", (4096, 1536), (1536, 256), (False, True)),
+    ])
+    def test_shape_rule_picks_widened_operands(self, case, dims, ash, bsh,
+                                               want):
+        """The operands enter pallas_call as bf16 exactly where the grid
+        reads their tiles more than once, widened by a convert under the
+        `fp8.quant` scope."""
+        from repro.analysis.jaxpr_walk import iter_jaxprs
+        from repro.kernels.fused_quant_matmul.ops import \
+            fused_quant_matmul as fqm
+        f8 = jnp.float8_e4m3fn
+        jaxpr = jax.make_jaxpr(functools.partial(
+            fqm, dims=dims, out_format="e4m3", with_amax=True))(
+            jax.ShapeDtypeStruct(ash, f8), jax.ShapeDtypeStruct(bsh, f8),
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            jax.ShapeDtypeStruct((1,), jnp.float32))
+        calls = [(e, jx) for jx, _ in iter_jaxprs(jaxpr) for e in jx.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1, case
+        call, jx = calls[0]
+        a_in, b_in = (v.aval.dtype for v in call.invars[:2])
+        assert (a_in == jnp.bfloat16, b_in == jnp.bfloat16) == want, case
+        assert {a_in, b_in} <= {jnp.dtype(jnp.bfloat16), jnp.dtype(f8)}
+        producer = {id(o): e for e in jx.eqns for o in e.outvars}
+        for v in call.invars[:2]:
+            if v.aval.dtype == jnp.bfloat16:
+                e = producer[id(v)]
+                assert e.primitive.name == "convert_element_type", case
+                assert "fp8.quant" in str(e.source_info.name_stack), case

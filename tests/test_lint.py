@@ -99,6 +99,41 @@ class TestVmemModel:
         assert not vm.gemm_vmem(256, 512, 256, budget=1024).fits
         assert vm.gemm_vmem(256, 512, 256).fits
 
+    def test_gemm_counts_each_operand_at_its_width(self):
+        """A bf16 operand block costs twice its fp8 bytes, in both DMA
+        buffers; the rest of the footprint does not move."""
+        base = vm.gemm_vmem(256, 512, 128)
+        wa = vm.gemm_vmem(256, 512, 128, a_bytes=2)
+        wb = vm.gemm_vmem(256, 512, 128, b_bytes=2)
+        both = vm.gemm_vmem(256, 512, 128, a_bytes=2, b_bytes=2)
+        assert wa.total_bytes - base.total_bytes == vm.DMA_BUF * 256 * 512
+        assert wb.total_bytes - base.total_bytes == vm.DMA_BUF * 512 * 128
+        assert both.total_bytes - base.total_bytes \
+            == vm.DMA_BUF * (256 * 512 + 512 * 128)
+        assert both.parts["out_blocks_x2"] == base.parts["out_blocks_x2"]
+
+    @pytest.mark.parametrize("mn,blocks,want", [
+        ((4096, 8960), (256, 256), (2, 2)),    # both read many times
+        ((8, 8960), (8, 256), (2, 1)),         # decode: weight read once
+        ((4096, 256), (256, 256), (1, 2)),     # k/v: A read once
+        ((256, 256), (256, 256), (1, 1)),      # a single output tile
+    ])
+    def test_operand_bytes_follow_the_widening_rule(self, mn, blocks, want):
+        assert vm.gemm_operand_bytes(*mn, *blocks) == want
+
+    def test_prune_sees_widened_tiles(self):
+        """A candidate that fits with fp8 operands but not once both are
+        widened is pruned when the sweep passes the call's (m, n)."""
+        cand = (512, 1024, 512)
+        fp8 = vm.gemm_vmem(*cand).total_bytes
+        wide = vm.gemm_vmem(*cand, a_bytes=2, b_bytes=2).total_bytes
+        budget = (fp8 + wide) // 2
+        kept, _ = vm.prune_gemm_candidates([cand], budget=budget)
+        assert kept == [cand]
+        kept, pruned = vm.prune_gemm_candidates([cand], mn=(4096, 4096),
+                                                budget=budget)
+        assert kept == [] and pruned[0]["vmem_bytes"] == wide
+
 
 # ---------------------------------------------------------------------------
 # autotune wiring: the sweep never times a pruned candidate
