@@ -2,5 +2,6 @@
 reference, the comparison that decides `correct`, and the trace reduction.
 
 Nothing here imports the program under test (`src/repro`) except the
-runner, `bench.train`, which runs it.
+runners (`bench/<kind>.py`, found by the traffic's kind), which run it, and
+the program-side functions of the architectures (`bench.arch`).
 """

@@ -1,7 +1,8 @@
-"""What both cell runners share: files found by name, the device, the compile
-cache and counter, and the result line."""
+"""What every cell runner shares: files found by name, the device, the
+compile cache and counter, and the result line."""
 from __future__ import annotations
 
+import importlib.util
 import json
 import math
 import os
@@ -12,6 +13,7 @@ HERE = Path(__file__).resolve().parents[1]      # chipbench/
 ROOT = HERE.parent                              # the checkout
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = HERE / ".traces"
+RUNNERS = HERE / "bench"
 
 
 def trace_dir(cellname: str) -> str:
@@ -44,6 +46,35 @@ def cell(name: str) -> tuple:
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     return (w, conf, load_json(ROOT / conf["file"]),
             load_json(HERE / "traffic" / f"{w['traffic']}.json"))
+
+
+def load_file(path: Path, name: str, what: str):
+    """The Python module in `path`, loaded once a process under `name`;
+    exits non-zero, naming the file, where there is none."""
+    if name in sys.modules:
+        return sys.modules[name]
+    if not path.is_file():
+        raise SystemExit(f"chipbench: no {what}: no file {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod
+
+
+def runner(kind: str):
+    """The runner of a traffic kind: `bench/<kind>.py`, whose `run(...)`
+    runs one cell."""
+    mod = load_file(RUNNERS / f"{kind}.py", f"bench.{kind}",
+                    f"runner for traffic of kind {kind!r}")
+    if not callable(getattr(mod, "run", None)):
+        raise SystemExit(f"chipbench: no runner for traffic of kind "
+                         f"{kind!r}: {mod.__file__} has no run()")
+    return mod
 
 
 def src_path():

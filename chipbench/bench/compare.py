@@ -20,36 +20,22 @@ are printed beside them:
   dropped_steps    steps of the three that the program's loss scaler
                    dropped on overflow.
 
-A leaf is one layer's slice of a parameter (or a whole non-layer leaf).
-Leaves whose reference first gradient is under a thousandth of the median
-leaf's move under Adam by round-off alone (a key bias under softmax), so
-they are left out of change_gap by that rule, not by name.
+A leaf is one layer's slice of a parameter (or a whole leaf of the
+frame), and each side gives its norms as {tag: float} (`bench.weights.
+flat`). Leaves whose reference first gradient is under a thousandth of the
+median leaf's move under Adam by round-off alone (a key bias under
+softmax), so they are left out of change_gap by that rule, not by name.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench.weights import LAYER_LEAVES
-
 TINY_GRAD = 1e-3
 
 
-def _flat(d: dict) -> dict:
-    out = {}
-    for n, v in d.items():
-        v = np.atleast_1d(np.asarray(v, np.float64))
-        if n not in LAYER_LEAVES:
-            out[n] = float(v[0])
-        else:
-            for i, x in enumerate(v):
-                out[f"{n}.{i}"] = float(x)
-    return out
-
-
-def leaf_gaps(prog: dict, ref: dict, keep=None) -> list:
+def leaf_gaps(p: dict, r: dict, keep=None) -> list:
     """[(gap, leaf, program norm, reference norm)] of every kept leaf,
     widest first."""
-    p, r = _flat(prog), _flat(ref)
     if set(p) != set(r):
         raise ValueError(f"leaves differ: {sorted(set(p) ^ set(r))}")
     med = float(np.median(list(r.values())))
@@ -62,8 +48,7 @@ def leaf_gaps(prog: dict, ref: dict, keep=None) -> list:
     return sorted(out, key=lambda t: -t[0])
 
 
-def moved_leaves(ref_grad: dict):
-    r = _flat(ref_grad)
+def moved_leaves(r: dict):
     med = float(np.median(list(r.values())))
     return {k for k, v in r.items() if v >= TINY_GRAD * med}
 
@@ -78,13 +63,12 @@ def train_readings(prog: dict, ref: dict) -> dict:
     if not np.isfinite(loss_gap):
         loss_gap = float("inf")
     if prog["grad"] is None:        # no step kept: nothing was learned
-        prog = dict(prog, grad={k: 0.0 * np.asarray(v)
-                                for k, v in ref["grad"].items()})
+        prog = dict(prog, grad={k: 0.0 for k in ref["grad"]})
     grad = leaf_gaps(prog["grad"], ref["grad"])
     change = leaf_gaps(prog["change"], ref["change"],
                        keep=moved_leaves(ref["grad"]))
-    gp = np.sqrt(sum(v * v for v in _flat(prog["grad"]).values()))
-    gr = np.sqrt(sum(v * v for v in _flat(ref["grad"]).values()))
+    gp = np.sqrt(sum(v * v for v in prog["grad"].values()))
+    gr = np.sqrt(sum(v * v for v in ref["grad"].values()))
     kept = prog.get("kept", ())
     dropped = sum(1 for k in kept if not k)
     mismatch = sum(1 for k, o in zip(kept, ref.get("overflow", ()))

@@ -8,8 +8,6 @@ out of the result line.
 """
 from __future__ import annotations
 
-import importlib.util
-
 from bench import common, peaks, trace
 
 
@@ -29,12 +27,9 @@ def selected(cellname: str) -> list:
 
 
 def reader(name: str):
-    path = common.HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return common.load_file(common.HERE / "metrics" / f"{name}.py",
+                            f"chipbench_metric_{name}",
+                            f"reader of metric {name!r}").read
 
 
 def read_all(cellname, work, device, chips, *, require=True):

@@ -1,9 +1,12 @@
-"""Plain float32 reference of the dense decoder, independent of the program.
+"""Plain float32 reference of a decoder LM, independent of the program.
 
-RMSNorm, rotary embeddings (half-split), grouped-query attention with QKV
-bias, a SwiGLU MLP and a tied or untied head, written in plain `jax.numpy`
-with every contraction at `Precision.HIGHEST`. It runs layer by layer and
-in blocks of rows, so that it fits one chip beside nothing else.
+The frame (token embedding, final RMSNorm, a tied or untied head, the
+loss) and the pieces that the architectures' layers are built from
+(`models/<arch>.py`: RMSNorm, rotary embeddings, causal grouped-query
+attention, products that can be tapped), written in plain `jax.numpy`
+with every contraction at `Precision.HIGHEST`. The training step runs
+layer by layer over the architecture's layer groups (`bench.arch`), and in
+blocks of rows, so that it fits one chip beside nothing else.
 
 `bits=4` turns it into the control: every matrix product (attention's two
 included) takes its operands, and in the backward pass its incoming
@@ -12,8 +15,9 @@ precision below the FP8 the configurations state.
 
 At full precision the training step also reads, in its backward pass, the
 largest magnitude of every tensor that the configured FP8 recipe stores
-in e5m2 without saturation (`SITES`), and `Overflow` says from those and
-the recipe's delayed scaling whether the loss scaler should drop the step.
+in e5m2 without saturation (each group's `sites`), and `Overflow` says
+from those and the recipe's delayed scaling whether the loss scaler should
+drop the step.
 """
 from __future__ import annotations
 
@@ -25,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bench.weights import LAYER_LEAVES, dims
+from bench import arch
 
 HI = lax.Precision.HIGHEST
 
@@ -78,17 +82,14 @@ def mm(spec, a, b, bits: Optional[int]):
 # and its weight gradient dW; attention's output error dO, dP = dO V^T (on
 # the attended positions) and the softmax gradient dS.
 
-GEMMS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-SITES = tuple(f"{g}.{t}" for g in GEMMS for t in ("dY", "dA", "dW")) \
-    + ("attn.dO", "attn.dP", "attn.dS")
-
-
 def _amax(x):
     return jnp.max(jnp.abs(x))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _tap_mm(spec, a, b, tok):
+def tap_mm(spec, a, b, tok):
+    """A product whose backward pass gives `tok` the largest magnitudes
+    of (dY, dA, dW)."""
     return _einsum(spec, a, b)
 
 
@@ -103,7 +104,7 @@ def _tap_mm_bwd(spec, res, g):
     return da, db, jnp.stack([_amax(g), _amax(da), _amax(db)])
 
 
-_tap_mm.defvjp(_tap_mm_fwd, _tap_mm_bwd)
+tap_mm.defvjp(_tap_mm_fwd, _tap_mm_bwd)
 
 
 @jax.custom_vjp
@@ -135,19 +136,6 @@ def attn_blocks(s: int, q_block: int) -> int:
     return 1 if s % q_block else nb
 
 
-def zero_taps(s: int, q_block: int) -> dict:
-    """The token inputs of one layer's taps."""
-    t = {g: jnp.zeros((3,), jnp.float32) for g in GEMMS}
-    t["attn"] = jnp.zeros((attn_blocks(s, q_block), 3), jnp.float32)
-    return t
-
-
-def tap_amax(gt: dict):
-    """One layer's taps' cotangents -> (len(SITES),) largest magnitudes."""
-    return jnp.concatenate([gt[g] for g in GEMMS]
-                           + [jnp.max(gt["attn"], axis=0)])
-
-
 class Overflow:
     """The configured recipe's overflow rule, applied to the reference's
     own tensors. Delayed scaling gives each site the dequantization scale
@@ -158,9 +146,10 @@ class Overflow:
     step has to be dropped, under `band[0]` kept, and between them either
     is sound: the program's FP8 tensors and their e5m2-rounded history
     differ from the reference's by that much. An overflowing site's history
-    takes growth x cap, and a dropped step halves the loss scale."""
+    takes growth x cap, and a dropped step halves the loss scale. `names`
+    names each amax a step gives (`site.layer`)."""
 
-    def __init__(self, recipe: dict, loss_scale: dict, band):
+    def __init__(self, recipe: dict, loss_scale: dict, band, names):
         self.fmax = float(recipe["fmax"])
         self.margin = float(recipe["margin"])
         self.growth = float(recipe["growth"])
@@ -168,11 +157,12 @@ class Overflow:
         self.backoff = float(loss_scale["backoff"])
         self.floor = float(loss_scale["min"])
         self.band = tuple(float(b) for b in band)
+        self.names = list(names)
         self.hist = None
         self.log = []
 
     def decide(self, amax, program_kept=None) -> bool:
-        """Whether the step whose (layers, sites) amaxes these are is kept:
+        """Whether the step whose amaxes these are is kept:
         the reference's own verdict outside the band, the program's within
         it (where no program is given, the ratio against 1)."""
         a = np.asarray(amax, np.float64) * self.scale
@@ -194,8 +184,7 @@ class Overflow:
         top = np.argsort(r, axis=None)[::-1][:3]
         self.log.append({"ratio": worst, "verdict": verdict, "kept": kept,
                          "loss_scale": self.scale,
-                         "top": [(float(r.flat[k]),
-                                  f"{SITES[k % r.shape[1]]}.{k // r.shape[1]}")
+                         "top": [(float(r.flat[k]), self.names[k])
                                  for k in top]})
         if not kept:
             self.scale = max(self.scale * self.backoff, self.floor)
@@ -250,33 +239,6 @@ def attention(q, k, v, q_pos, k_pos, *, bits, q_block: int, tok=None):
     return out.swapaxes(0, 1).reshape(b, s, h, d)
 
 
-def layer(p, x, pos, z, *, bits=None, q_block=512, taps=None):
-    """One decoder layer; p holds this layer's leaves (no layer axis).
-    `taps` (`zero_taps`) reads the backward tensors of every product."""
-    b, s, _ = x.shape
-    hd = z["hd"]
-
-    def proj(spec, a, name):
-        if taps is None:
-            return mm(spec, a, p[name], bits)
-        return _tap_mm(spec, a, p[name], taps[name])
-
-    y = rmsnorm(x, p["ln1"], z["eps"])
-    q = proj("bsd,dn->bsn", y, "wq") + p["bq"]
-    k = proj("bsd,dn->bsn", y, "wk") + p["bk"]
-    v = proj("bsd,dn->bsn", y, "wv") + p["bv"]
-    q = rope(q.reshape(b, s, z["h"], hd), pos, z["theta"])
-    k = rope(k.reshape(b, s, z["hkv"], hd), pos, z["theta"])
-    v = v.reshape(b, s, z["hkv"], hd)
-    o = attention(q, k, v, pos, pos, bits=bits, q_block=q_block,
-                  tok=None if taps is None else taps["attn"])
-    x = x + proj("bsn,nd->bsd", o.reshape(b, s, -1), "wo")
-    y = rmsnorm(x, p["ln2"], z["eps"])
-    a = jax.nn.silu(proj("bsd,df->bsf", y, "w_gate")) \
-        * proj("bsd,df->bsf", y, "w_up")
-    return x + proj("bsf,fd->bsd", a, "w_down")
-
-
 def head_matrix(w):
     """(matrix, einsum) of the output head: the untied head (d, V), or the
     tied embedding (V, d) used as its transpose."""
@@ -285,8 +247,9 @@ def head_matrix(w):
     return w["embed"], "nd,vd->nv"
 
 
-def layer_params(w, i):
-    return {n: w["layers"][n][i] for n in LAYER_LEAVES}
+def layer_params(wg: dict, i: int) -> dict:
+    """Layer i's leaves of a group's sub-tree (no layer axis)."""
+    return {n: x[i] for n, x in wg.items()}
 
 
 def adam(o, p, mu, nu, g, count):
@@ -303,7 +266,8 @@ def adam(o, p, mu, nu, g, count):
 class Trainer:
     """Three (or more) Adam steps of the reference on one chip's memory.
 
-    The forward pass keeps each layer's input; the backward pass takes one
+    The layers are the architecture's groups, one after the other. The
+    forward pass keeps each layer's input; the backward pass takes one
     layer's vector-Jacobian product at a time (recomputing inside it) and
     moves that layer's gradient to the host, so no whole gradient tree is
     ever alive on the chip; once the loss scaler's verdict is in, each
@@ -315,23 +279,20 @@ class Trainer:
 
     def __init__(self, m: dict, opt: dict, *, bits=None, rows: int = 512,
                  q_block: int = 512):
-        self.z = z = dims(m)
+        a = arch.load(m)
+        self.z = z = a.dims(m)
+        self.groups = a.groups(z)
         self.opt = opt
         self.rows = rows
         self.q_block = q_block
         self.tapped = bits is None
-        lay = functools.partial(layer, z=z, bits=bits, q_block=q_block)
-        self._layer = jax.jit(lay)
-        if self.tapped:
-            self._layer_vjp = jax.jit(
-                lambda p, x, pos, t, g: jax.vjp(
-                    lambda pp, xx, tt: lay(pp, xx, pos, taps=tt),
-                    p, x, t)[1](g))
-        else:
-            self._layer_vjp = jax.jit(
-                lambda p, x, pos, t, g: jax.vjp(
-                    lambda pp, xx: lay(pp, xx, pos), p, x)[1](g) + (None,))
-        self._tap_amax = jax.jit(tap_amax)
+        self._layer, self._layer_vjp, self._tap_amax = {}, {}, {}
+        self.site_names, first = [], 0
+        for g in self.groups:
+            self._jit_group(g, bits)
+            self.site_names += [f"{t}.{first + i}" for i in range(g.depth)
+                                for t in g.sites]
+            first += g.depth
         self._embed = jax.jit(lambda e, t: e[t])
         self._head = jax.jit(self._head_block_fn(), static_argnums=(0,),
                              donate_argnums=(8,))
@@ -345,6 +306,23 @@ class Trainer:
         self._embed_grad = jax.jit(
             lambda ge, t, gx: ge.at[t.reshape(-1)].add(
                 gx.reshape(-1, gx.shape[-1])), donate_argnums=(0,))
+
+    def _jit_group(self, g, bits):
+        """A group's layer, its vector-Jacobian product (with the taps'
+        cotangents where tapped, else None) and its taps' gathering."""
+        lay = functools.partial(g.block, z=self.z, bits=bits,
+                                q_block=self.q_block)
+        self._layer[g.name] = jax.jit(lay)
+        if self.tapped:
+            self._layer_vjp[g.name] = jax.jit(
+                lambda p, x, pos, t, gy: jax.vjp(
+                    lambda pp, xx, tt: lay(pp, xx, pos, taps=tt),
+                    p, x, t)[1](gy))
+        else:
+            self._layer_vjp[g.name] = jax.jit(
+                lambda p, x, pos, t, gy: jax.vjp(
+                    lambda pp, xx: lay(pp, xx, pos), p, x)[1](gy) + (None,))
+        self._tap_amax[g.name] = jax.jit(g.tap_amax)
 
     def _head_block_fn(self):
         z = self.z
@@ -364,31 +342,36 @@ class Trainer:
         return fn
 
     def init_state(self, w):
-        host = {n: np.zeros(v.shape, np.float32)
-                for n, v in w.items() if n != "layers"}
-        dev = {n: jnp.zeros_like(v) for n, v in w["layers"].items()}
-        return {"w": w, "count": 0,
-                "mu": dict(host, layers=dev),
-                "nu": {**{n: v.copy() for n, v in host.items()},
-                       "layers": {n: jnp.zeros_like(v)
-                                  for n, v in w["layers"].items()}}}
+        groups = {g.name for g in self.groups}
+
+        def moments():
+            out = {n: np.zeros(v.shape, np.float32)
+                   for n, v in w.items() if n not in groups}
+            for g in groups:
+                out[g] = {n: jnp.zeros_like(v) for n, v in w[g].items()}
+            return out
+        return {"w": w, "count": 0, "mu": moments(), "nu": moments()}
 
     def step(self, st, batch, *, on_grad=None, decide=None):
         """One Adam step on {"tokens", "labels", "loss_mask"}; returns the
-        loss. `on_grad(name, layer or None, grad)` sees every gradient of
-        a step that is applied. `decide(amax)`, given the (layers, SITES)
-        largest magnitudes of the step's backward tensors, says whether
-        the step is applied (the loss scaler's verdict); without it every
-        step is. The gradients wait on the host for the verdict."""
-        w, z = st["w"], self.z
+        loss. `on_grad(group, name, layer, grad)` sees every gradient of a
+        step that is applied (group and layer None for a leaf of the
+        frame; the layer counted within its group). `decide(amax)`, given
+        the largest magnitudes of the step's backward tensors in the order
+        of `site_names`, says whether the step is applied (the loss
+        scaler's verdict); without it every step is. The gradients wait on
+        the host for the verdict."""
+        w = st["w"]
         tok = jnp.asarray(batch["tokens"])
         lab = jnp.asarray(batch["labels"])
         mask = jnp.asarray(batch["loss_mask"], jnp.float32)
         b, s = tok.shape
         pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         xs = [self._embed(w["embed"], tok)]
-        for i in range(z["L"]):
-            xs.append(self._layer(layer_params(w, i), xs[-1], pos))
+        for g in self.groups:
+            for i in range(g.depth):
+                xs.append(self._layer[g.name](layer_params(w[g.name], i),
+                                              xs[-1], pos))
         hm, spec = head_matrix(w)
         denom = jnp.maximum(mask.sum(), 1.0)
         xf = xs.pop().reshape(b * s, -1)
@@ -405,15 +388,16 @@ class Trainer:
         loss = float(loss / denom)
         del xf
         gx = jnp.concatenate(gxs).reshape(b, s, -1)
-        taps = zero_taps(s, self.q_block) if self.tapped else None
         held, amax = {}, []
-        for i in reversed(range(z["L"])):
-            gp, gx, gt = self._layer_vjp(layer_params(w, i), xs.pop(), pos,
-                                         taps, gx)
-            if gt is not None:
-                amax.append(np.asarray(self._tap_amax(gt)))
-            held[i] = jax.device_get(gp)
-            del gp
+        for g in reversed(self.groups):
+            taps = g.zero_taps(s, self.q_block) if self.tapped else None
+            for i in reversed(range(g.depth)):
+                gp, gx, gt = self._layer_vjp[g.name](
+                    layer_params(w[g.name], i), xs.pop(), pos, taps, gx)
+                if gt is not None:
+                    amax.append(np.asarray(self._tap_amax[g.name](gt)))
+                held[g.name, i] = jax.device_get(gp)
+                del gp
         if "head" in w:
             grads = {"head": ghm,
                      "embed": self._embed_grad(jnp.zeros_like(w["embed"]),
@@ -423,15 +407,16 @@ class Trainer:
         del ghm, gx
         grads["final_norm"] = gfn
         grads = {n: np.asarray(g) for n, g in grads.items()}
-        if decide is not None and not decide(np.stack(amax[::-1])):
+        if decide is not None and not decide(np.concatenate(amax[::-1])):
             return loss
         st["count"] += 1
-        for i in reversed(range(z["L"])):
-            self._apply_layer(st, i, held.pop(i), on_grad)
+        for g in reversed(self.groups):
+            for i in reversed(range(g.depth)):
+                self._apply_layer(st, g, i, held.pop((g.name, i)), on_grad)
         for n in list(grads):
             g = grads.pop(n)
             if on_grad:
-                on_grad(n, None, g)
+                on_grad(None, n, None, g)
             p = np.asarray(w[n])
             p, st["mu"][n], st["nu"][n] = adam(
                 self.opt, p, st["mu"][n], st["nu"][n], g,
@@ -439,13 +424,15 @@ class Trainer:
             w[n] = jnp.asarray(p.astype(np.float32))
         return loss
 
-    def _apply_layer(self, st, i, gp, on_grad):
-        """Adam on layer i's leaves, from its gradient on the host."""
-        L, mu, nu = st["w"]["layers"], st["mu"]["layers"], st["nu"]["layers"]
+    def _apply_layer(self, st, group, i, gp, on_grad):
+        """Adam on the leaves of layer i of a group, from its gradient on
+        the host."""
+        k = group.name
+        L, mu, nu = st["w"][k], st["mu"][k], st["nu"][k]
         count = jnp.int32(st["count"])
-        for n in LAYER_LEAVES:
+        for n in group.leaves:
             g = jnp.asarray(gp[n])
             if on_grad:
-                on_grad(n, i, g)
+                on_grad(k, n, i, g)
             L[n], mu[n], nu[n] = self._adam_layer(L[n], mu[n], nu[n], g,
                                                   jnp.int32(i), count)

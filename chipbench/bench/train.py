@@ -7,7 +7,9 @@ the on-metrics callback marks the window's start after the warm-up steps
 and stops the loop once `seconds` have passed. While the first three steps
 run, the readings that `correct` compares are taken from the state the
 step returns: the first gradient from Adam's first moment after step 1,
-and each leaf's change after step 3.
+and each leaf's change after step 3. The architecture's module
+(`bench.arch`) gives the program's configuration and where each leaf lives
+in the program's parameters.
 """
 from __future__ import annotations
 
@@ -19,64 +21,32 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import common, compare, weights, workload
+from bench import arch, common, compare, weights, workload
 from bench.common import say
 
-# Reference-layout leaf -> the program's parameter path (scanned stack).
-PROGRAM_PATH = {
-    "embed": ("embed", "table"), "head": ("embed", "head"),
-    "final_norm": ("final_norm", "scale"),
-    "ln1": ("decoder", "stack_0", "norm1", "scale"),
-    "wq": ("decoder", "stack_0", "attn", "wq"),
-    "bq": ("decoder", "stack_0", "attn", "bq"),
-    "wk": ("decoder", "stack_0", "attn", "wk"),
-    "bk": ("decoder", "stack_0", "attn", "bk"),
-    "wv": ("decoder", "stack_0", "attn", "wv"),
-    "bv": ("decoder", "stack_0", "attn", "bv"),
-    "wo": ("decoder", "stack_0", "attn", "wo"),
-    "ln2": ("decoder", "stack_0", "norm2", "scale"),
-    "w_gate": ("decoder", "stack_0", "mlp", "gate"),
-    "w_up": ("decoder", "stack_0", "mlp", "up"),
-    "w_down": ("decoder", "stack_0", "mlp", "down"),
-}
 CHECK_STEPS = 3
 
 
-def program_config(m: dict):
-    """The program's ModelConfig for a configuration file: the preset
-    named in `program.arch`, its `program.set` overrides, and every size
-    from the file."""
-    from repro.launch.train import train_config
-    p = m["program"]
-    cfg, _ = train_config(p["arch"], overrides=p["set"])
-    return cfg.replace(
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], head_dim=m.get("head_dim"),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        norm_eps=float(m["rms_norm_eps"]), rope_theta=float(m["rope_theta"]),
-        qkv_bias=True, act=m["hidden_act"])
+def batches(m: dict, mix: dict, seed: int) -> list:
+    """The mix's pool of batches for a seed, over the model's vocabulary."""
+    return workload.train_batches(
+        seed, vocab=arch.load(m).dims(m)["V"], batch=mix["batch"],
+        seq=mix["seq"], n=mix["pool"], temperature=mix["temperature"])
 
 
-def flat_names(w: dict):
-    """(name, array) pairs of a reference-layout tree."""
-    out = [(n, v) for n, v in w.items() if n != "layers"]
-    return out + list(w["layers"].items())
-
-
-def to_program(w: dict, cfg):
+def to_program(w: dict, m: dict, cfg):
     """A reference-layout tree in the program's parameter layout, checked
     against the layout the program itself makes."""
     from repro.models.transformer import init_lm
     want = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+    paths = arch.load(m).PROGRAM_PATH
     out: dict = {}
-    for name, v in flat_names(w):
+    for g, name, _ in weights.leaves(m):
         node = out
-        path = PROGRAM_PATH[name]
+        path = paths[g, name]
         for p in path[:-1]:
             node = node.setdefault(p, {})
-        node[path[-1]] = v
+        node[path[-1]] = weights.get(w, g, name)
     got = jax.tree_util.tree_map(lambda x: (x.shape,), out)
     exp = jax.tree_util.tree_map(lambda x: (x.shape,), want)
     if got != exp:
@@ -85,34 +55,27 @@ def to_program(w: dict, cfg):
     return out
 
 
-def program_leaf(tree, name):
-    for p in PROGRAM_PATH[name]:
+def program_leaf(tree, path):
+    for p in path:
         tree = tree[p]
     return tree
 
 
-def leaf_norms(tree, names):
-    """Per-layer norms (f32) of each named program leaf: a vector over
-    layers for layer leaves, a scalar otherwise."""
-    out = {}
-    for n in names:
-        x = program_leaf(tree, n).astype(jnp.float32)
-        if n in weights.LAYER_LEAVES:
-            out[n] = jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
-        else:
-            out[n] = jnp.sqrt(jnp.sum(x * x))
-    return out
+def norm(x, per_layer: bool):
+    """The norm of x, or of each layer's slice of a group's leaf."""
+    if per_layer:
+        return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
+    return jnp.sqrt(jnp.sum(x * x))
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 4))
-def change_norm(name, shape, x, key, dtype):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 6))
+def change_norm(init, group, name, shape, x, key, dtype):
     """Per-layer norms of a leaf's change from the value the seed gave it
-    (the seed's key is an argument, so one program serves every seed)."""
-    d = x.astype(jnp.float32) - weights.leaf(key, name, shape, dtype) \
+    (`init`, the architecture's `leaf`; the seed's key is an argument, so
+    one program serves every seed)."""
+    d = x.astype(jnp.float32) - init(key, group, name, shape, dtype) \
         .astype(jnp.float32)
-    if name in weights.LAYER_LEAVES:
-        return jnp.sqrt(jnp.sum(d * d, axis=tuple(range(1, d.ndim))))
-    return jnp.sqrt(jnp.sum(d * d))
+    return norm(d, group is not None)
 
 
 class Readings:
@@ -124,12 +87,16 @@ class Readings:
         self.inner = loop._step_fn
         self.calls = 0
         self.grad_norms = self.change_norms = None
-        self.names = [n for n in weights.shapes(m) if n != "layers"] \
-            + list(weights.LAYER_LEAVES)
-        self._grad = jax.jit(lambda mu: leaf_norms(
-            jax.tree_util.tree_map(lambda x: x / (1.0 - b1), mu),
-            self.names))
+        a = arch.load(m)
+        # by name: the order in which `compare` sums the gradient's norm
+        self.leaves = sorted(weights.leaves(m), key=lambda t: t[1])
+        self.paths = [a.PROGRAM_PATH[g, n] for g, n, _ in self.leaves]
+        self._grad = jax.jit(lambda mu: [
+            norm((program_leaf(mu, path) / (1.0 - b1)).astype(jnp.float32),
+                 g is not None)
+            for (g, _, _), path in zip(self.leaves, self.paths)])
         self.m = m
+        self.init = a.leaf
         self.key = weights.seed_key(seed)
         self.dtype = jnp.dtype(master_dtype)
         loop._step_fn = self
@@ -141,14 +108,17 @@ class Readings:
         if self.grad_norms is None and bool(out[1]["grads_finite"]):
             # Adam's first moment after the first step the loss scaler
             # kept is (1 - b1) x that step's unscaled gradient.
-            self.grad_norms = {k: np.asarray(v) for k, v in
-                               self._grad(carried.opt_state["mu"]).items()}
+            norms = self._grad(carried.opt_state["mu"])
+            self.grad_norms = weights.flat(self.m, {
+                (g, n): np.asarray(v)
+                for (g, n, _), v in zip(self.leaves, norms)})
         if self.calls == CHECK_STEPS:
-            self.change_norms = {
-                n: np.asarray(change_norm(
-                    n, weights.leaf_shape(self.m, n),
-                    program_leaf(carried.master, n), self.key, self.dtype))
-                for n in self.names}
+            self.change_norms = weights.flat(self.m, {
+                (g, n): np.asarray(change_norm(
+                    self.init, g, n, shape,
+                    program_leaf(carried.master, path), self.key,
+                    self.dtype))
+                for (g, n, shape), path in zip(self.leaves, self.paths)})
             self.loop._step_fn = self.inner
         return out
 
@@ -160,12 +130,10 @@ def run(cellname, chips, m, mix, args, t_process, counter):
     seed, seconds, tracing = args.seed, args.seconds, args.trace
     p = m["program"]
     opt = p["optimizer"]
-    cfg = program_config(m)
-    z = weights.dims(m)
+    a = arch.load(m)
+    cfg = a.program_config(m)
     bsz, seq = mix["batch"], mix["seq"]
-    pool = workload.train_batches(seed, vocab=z["V"], batch=bsz, seq=seq,
-                                  n=mix["pool"],
-                                  temperature=mix["temperature"])
+    pool = batches(m, mix, seed)
     say(f"t+{time.perf_counter() - t_process:.1f} s: batches made")
     loop = make_train_loop(cfg, steps=1 << 40, batch=bsz, seq=seq,
                            lr=opt["lr"], seed=seed, log_every=1 << 40)
@@ -191,7 +159,7 @@ def run(cellname, chips, m, mix, args, t_process, counter):
         jax.block_until_ready(loop.monitor.scaler.min_scale_at(
             np.asarray(0)))
     made = {"w": jax.jit(lambda k: to_program(weights.make_tree(
-        k, m, jnp.dtype(p["master_dtype"])), cfg))(weights.seed_key(seed))}
+        k, m, jnp.dtype(p["master_dtype"])), m, cfg))(weights.seed_key(seed))}
 
     say(f"t+{time.perf_counter() - t_process:.1f} s: weights made")
 
@@ -269,9 +237,18 @@ def run(cellname, chips, m, mix, args, t_process, counter):
     prog = {"losses": st["losses"][:CHECK_STEPS], "kept": kept,
             "grad": readings.grad_norms, "change": readings.change_norms}
     attempted = st["steps"]
-    window_losses = st["losses"][warm:]
-    failed = sum(1 for x, f in zip(window_losses, st["finite"][warm:])
-                 if not (np.isfinite(x) and f))
+    # A step fails when its loss is not finite: the forward pass broke. A
+    # step whose scaled gradients overflow is the recipe's loss scaler at
+    # work (the step is dropped and the scale backed off, as the
+    # configuration states, and `drop_mismatch` holds the first steps'
+    # drops to the reference's), so it is counted apart as `dropped`.
+    window_losses = st["losses"][warm:warm + attempted]
+    window_finite = st["finite"][warm:warm + attempted]
+    failed = sum(1 for x in window_losses if not np.isfinite(x))
+    dropped = [i for i, f in enumerate(window_finite) if not f]
+    say(f"window: {failed} steps with a loss that is not finite; the loss "
+        f"scaler dropped {len(dropped)} of {attempted} steps, at window "
+        f"steps {dropped}; loss scale by step {st['scale']}")
     del out, loop, readings, made
     gc.collect()
 
@@ -289,8 +266,8 @@ def run(cellname, chips, m, mix, args, t_process, counter):
     say(f"widest leaves (gap, leaf, program, reference): {widest}")
     tokens = attempted * bsz * seq
     result = {"attempted": attempted, "failed": failed,
-              "memory_peak_bytes": peak, "window_s": window,
-              "setup_s": setup_s, "tokens": tokens,
+              "dropped": len(dropped), "memory_peak_bytes": peak,
+              "window_s": window, "setup_s": setup_s, "tokens": tokens,
               "compiles_in_window": compiles, "widest": widest,
               "readings": rd}
     if not tracing:
@@ -299,7 +276,8 @@ def run(cellname, chips, m, mix, args, t_process, counter):
                                    "unit": "tokens/s"},
             "setup_s": {"value": setup_s, "unit": "s"}}
     else:
-        result["work"] = {"kind": "train", "z": z, "batch": bsz, "seq": seq,
+        result["work"] = {"kind": "train", "arch": m["architectures"][0],
+                          "z": a.dims(m), "batch": bsz, "seq": seq,
                           "steps": attempted, "tokens": tokens}
     return result, checks
 
@@ -318,24 +296,22 @@ def reference_readings(m, opt, batches, seed, master_dtype,
     from bench.reference import Overflow, Trainer
     tr = Trainer(m, opt, bits=bits)
     mdt = jnp.dtype(master_dtype)
-    w = weights.make(seed, m, mdt)
-    w = {k: (v.astype(jnp.float32) if k != "layers" else
-             {n: x.astype(jnp.float32) for n, x in v.items()})
-         for k, v in w.items()}
+    w = jax.tree_util.tree_map(lambda v: v.astype(jnp.float32),
+                               weights.make(seed, m, mdt))
     st = tr.init_state(w)
     grads = {}
     p = m["program"]
     ovf = Overflow(p["delayed_scaling"], p["loss_scale"],
-                   band or m["limits"]["overflow_band"]) \
+                   band or m["limits"]["overflow_band"], tr.site_names) \
         if tr.tapped else None
 
-    def on_grad(name, i, g):
+    def on_grad(group, name, i, g):
         if st["count"] == 1:
             v = float(jnp.sqrt(jnp.sum(g * g)))
             if i is None:
-                grads[name] = v
+                grads[group, name] = v
             else:
-                grads.setdefault(name, {})[i] = v
+                grads.setdefault((group, name), {})[i] = v
 
     losses = []
     for i, b in enumerate(batches):
@@ -344,12 +320,13 @@ def reference_readings(m, opt, batches, seed, master_dtype,
         decide = None if ovf is None else functools.partial(
             ovf.decide, program_kept=None if kept is None else kept[i])
         losses.append(tr.step(st, b, on_grad=on_grad, decide=decide))
-    key = weights.seed_key(seed)
-    change = {n: np.asarray(change_norm(n, weights.leaf_shape(m, n), x, key,
-                                        mdt))
-              for n, x in flat_names(st["w"])}
-    grads = {n: (np.asarray([v[i] for i in range(len(v))])
-                 if isinstance(v, dict) else np.asarray(v))
-             for n, v in grads.items()}
+    key, init = weights.seed_key(seed), arch.load(m).leaf
+    change = weights.flat(m, {
+        (g, n): np.asarray(change_norm(init, g, n, shape,
+                                       weights.get(st["w"], g, n), key, mdt))
+        for g, n, shape in weights.leaves(m)})
+    grads = weights.flat(m, {
+        k: [v[i] for i in range(len(v))] if isinstance(v, dict) else v
+        for k, v in grads.items()})
     return {"losses": losses, "grad": grads, "change": change,
             "overflow": [] if ovf is None else ovf.log}

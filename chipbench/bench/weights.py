@@ -1,18 +1,17 @@
 """Weights made from a seed, in the plain reference's layout.
 
-Every leaf is drawn from its own key, folded from the seed and the leaf's
-name, so one leaf can be made again alone (`leaf`), and the whole set comes
-from one jitted call (`make`). The cell runners hand the same values to the
-program in its own layout (`bench.train.to_program`); the reference makes
-them anew after the program's state is freed.
+The layout is the architecture's (`bench.arch`): the frame's leaves at the
+top, and one sub-tree for each layer group, whose leaves stack the group's
+layers on a leading axis. Every leaf is drawn from its own key, folded from
+the seed and the leaf's name (`fold`), so one leaf can be made again alone
+(the architecture's `leaf`), and the whole set comes from one jitted call
+(`make`). The cell runners hand the same values to the program in its own
+layout (`bench.train.to_program`); the reference makes them anew after the
+program's state is freed.
 
-Layout (L layers stacked on the leading axis; d = hidden, q = heads x head
-size, k = kv heads x head size, f = intermediate, V = vocab):
-
-    embed (V, d)  head (d, V) when untied  final_norm (d,)
-    layers: ln1 (L, d)  wq (L, d, q)  bq (L, q)  wk (L, d, k)  bk (L, k)
-            wv (L, d, k)  bv (L, k)  wo (L, q, d)  ln2 (L, d)
-            w_gate (L, d, f)  w_up (L, d, f)  w_down (L, f, d)
+A reading of each leaf (a norm, a gap) goes by the leaf's tag: its name
+for a leaf of the frame, `name.i` for layer i of the model, counted
+across the groups in order (`flat`).
 """
 from __future__ import annotations
 
@@ -20,39 +19,24 @@ import zlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-LAYER_LEAVES = ("ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo", "ln2",
-                "w_gate", "w_up", "w_down")
-
-
-def dims(m: dict) -> dict:
-    """Sizes of a configuration file's model (HF key names)."""
-    d = m["hidden_size"]
-    h = m["num_attention_heads"]
-    hd = m.get("head_dim") or d // h
-    return dict(d=d, h=h, hkv=m["num_key_value_heads"], hd=hd,
-                f=m["intermediate_size"], V=m["vocab_size"],
-                L=m["num_hidden_layers"], tied=bool(m["tie_word_embeddings"]),
-                eps=float(m["rms_norm_eps"]), theta=float(m["rope_theta"]))
+from bench import arch
 
 
-def shapes(m: dict) -> dict:
-    z = dims(m)
-    d, L, q, k, f, V = (z["d"], z["L"], z["h"] * z["hd"], z["hkv"] * z["hd"],
-                        z["f"], z["V"])
-    layers = {"ln1": (L, d), "wq": (L, d, q), "bq": (L, q), "wk": (L, d, k),
-              "bk": (L, k), "wv": (L, d, k), "bv": (L, k), "wo": (L, q, d),
-              "ln2": (L, d), "w_gate": (L, d, f), "w_up": (L, d, f),
-              "w_down": (L, f, d)}
-    out = {"embed": (V, d), "final_norm": (d,), "layers": layers}
-    if not z["tied"]:
-        out["head"] = (d, V)
+def leaves(m: dict) -> list:
+    """(group or None, name, shape) of every leaf, the frame's first."""
+    a = arch.load(m)
+    z = a.dims(m)
+    out = [(None, n, tuple(s)) for n, s in a.top(z).items()]
+    for g in a.groups(z):
+        out += [(g.name, n, (g.depth,) + tuple(s))
+                for n, s in g.leaves.items()]
     return out
 
 
-def leaf_shape(m: dict, name: str) -> tuple:
-    s = shapes(m)
-    return tuple(s["layers"][name] if name in LAYER_LEAVES else s[name])
+def get(tree: dict, group, name: str):
+    return tree[name] if group is None else tree[group][name]
 
 
 def seed_key(seed: int):
@@ -62,34 +46,17 @@ def seed_key(seed: int):
                               seed & 0xFFFFFFFF)
 
 
-def _std(name: str, shape) -> float:
-    if name in ("embed",):
-        return 0.02
-    fan_in = shape[-2]
-    scale = 0.5 if name in ("wo", "w_down") else 1.0
-    if name == "head":
-        scale = 0.5
-    return scale / fan_in ** 0.5
-
-
-def leaf(key, name: str, shape, dtype):
-    """One leaf from the seed's key: norms about 1, biases about 0.02,
-    matrices normal with a 1/sqrt(fan-in) scale, the embedding at 0.02."""
-    k = jax.random.fold_in(key, zlib.crc32(name.encode()))
-    if name in ("ln1", "ln2", "final_norm"):
-        x = 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
-    elif name in ("bq", "bk", "bv"):
-        x = 0.02 * jax.random.normal(k, shape, jnp.float32)
-    else:
-        x = _std(name, shape) * jax.random.normal(k, shape, jnp.float32)
-    return x.astype(dtype)
+def fold(key, name: str):
+    """The key of the leaf called `name`."""
+    return jax.random.fold_in(key, zlib.crc32(name.encode()))
 
 
 def make_tree(key, m: dict, dtype):
-    s = shapes(m)
-    out = {n: leaf(key, n, s[n], dtype) for n in s if n != "layers"}
-    out["layers"] = {n: leaf(key, n, sh, dtype)
-                     for n, sh in s["layers"].items()}
+    a = arch.load(m)
+    out: dict = {}
+    for g, n, shape in leaves(m):
+        node = out if g is None else out.setdefault(g, {})
+        node[n] = a.leaf(key, g, n, shape, dtype)
     return out
 
 
@@ -100,3 +67,23 @@ def make(seed: int, m: dict, dtype=jnp.float32, *, device=None):
     if device is not None:
         key = jax.device_put(key, device)
     return fn(key)
+
+
+def flat(m: dict, values: dict) -> dict:
+    """{tag: float} of readings keyed (group or None, name): a scalar for
+    a leaf of the frame, one value a layer for a group's leaf. The tags
+    keep the order of `values`, a group's layers in order."""
+    a = arch.load(m)
+    first, i = {}, 0
+    for g in a.groups(a.dims(m)):
+        first[g.name] = i
+        i += g.depth
+    out = {}
+    for (g, n), v in values.items():
+        v = np.atleast_1d(np.asarray(v, np.float64))
+        if g is None:
+            out[n] = float(v[0])
+        else:
+            for j, x in enumerate(v):
+                out[f"{n}.{first[g] + j}"] = float(x)
+    return out

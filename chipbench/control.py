@@ -27,15 +27,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import run  # noqa: E402
-from bench import common, compare, train, workload  # noqa: E402
+from bench import common, compare, train  # noqa: E402
 
 
 def batches(m, mix, seed):
-    pool = workload.train_batches(seed, vocab=m["vocab_size"],
-                                  batch=mix["batch"], seq=mix["seq"],
-                                  n=mix["pool"],
-                                  temperature=mix["temperature"])
-    return pool[:train.CHECK_STEPS]
+    return train.batches(m, mix, seed)[:train.CHECK_STEPS]
 
 
 def readings(cellname, seed, seconds, cell=None, require_chip=True):

@@ -1,6 +1,7 @@
-"""Least time of causal attention forward and backward (bench.counts) over
-the device time of the fp8_attention kernels."""
-from bench import counts, trace
+"""Least time of causal attention forward and backward (the
+architecture's `train_attn_least_time`) over the device time of the
+fp8_attention kernels."""
+from bench import arch, trace
 
 
 def read(ctx):
@@ -8,6 +9,6 @@ def read(ctx):
     ker = trace.kernel_s(ctx["trace"], "fp8_attention")
     if w["kind"] != "train" or ker <= 0:
         return None
-    least = w["steps"] * counts.train_attn_least_time(
+    least = w["steps"] * arch.by_name(w["arch"]).train_attn_least_time(
         w["z"], w["batch"], w["seq"], ctx["peaks"])
     return 100.0 * least / ker

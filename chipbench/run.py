@@ -6,9 +6,11 @@
 
 Runs one cell of BENCHMARK.json from the root of a checkout, on the chips
 the machine holds. Everything a cell needs is found by name: its
-configuration file (`configs/`), its traffic mix (`traffic/<mix>.json`,
-read by `bench.workload`), and with `--trace 1` each per-layer metric's
-reader (`metrics/<name>.py`). The last line of standard output is the
+configuration file (`configs/`) and the module of its architecture
+(`models/<architectures[0]>.py`), its traffic mix (`traffic/<mix>.json`,
+read by `bench.workload`) and the runner of the mix's kind
+(`bench/<kind>.py`), and with `--trace 1` each per-layer metric's reader
+(`metrics/<name>.py`). The last line of standard output is the
 result as JSON; the numbers that decide `correct` are printed beside their
 limits as the last lines of standard error and under `checks`, the last
 key of the result. Without an accelerator, or with fewer chips than the
@@ -50,15 +52,14 @@ def run_cell(args, *, cell=None, require_chip=True, t_process=T_PROCESS):
     common.use_cache()
     device = common.device_info(work["chips"], require=require_chip)
     counter = common.CompileCounter()
-    if mix["kind"] != "train":
-        raise SystemExit(f"chipbench: no runner for traffic of kind "
-                         f"{mix['kind']!r}")
-    from bench import train as runner
+    runner = common.runner(mix["kind"])
     out, checks = runner.run(args.workload, work["chips"], m, mix, args,
                              t_process, counter)
     device["memory_peak_bytes"] = out.pop("memory_peak_bytes")
     result = {"correct": passed(checks),
               "attempted": out["attempted"], "failed": out["failed"]}
+    if "dropped" in out:
+        result["dropped"] = out["dropped"]
     if args.trace:
         red, values = metrics.read_all(args.workload, out["work"], device,
                                        work["chips"], require=require_chip)

@@ -8,7 +8,7 @@ import sys
 
 import pytest
 
-from bench import common, metrics
+from bench import arch, common, metrics
 
 BENCH = common.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -42,7 +42,8 @@ def test_each_cell_finds_its_files_and_metrics(w):
     work, conf, m, mix = common.cell(w["name"])
     assert (common.HERE / "traffic" / f"{w['traffic']}.json").is_file()
     assert set(conf["reduced"]) == set(m["reduced"])
-    assert mix["kind"] == "train"
+    assert callable(common.runner(mix["kind"]).run)
+    assert callable(arch.load(m).dims)
     e2e = [x["name"] for x in BENCH["end_to_end"]
            if w["name"] in x.get("workloads", [w["name"]])]
     assert "setup_s" in e2e and len(e2e) >= 2

@@ -65,3 +65,38 @@ def test_train_fault_is_caught(no_cache, monkeypatch, fault):
     assert not result["correct"], checks
     if fault == "spurious_drop":
         assert checks["drop_mismatch"]["value"] >= 1, checks
+
+
+@pytest.mark.parametrize("mark", ["drop", "nan_loss"])
+def test_window_counts_drops_apart_from_failures(no_cache, monkeypatch,
+                                                 mark):
+    """A window step the loss scaler drops is counted as `dropped`, and
+    only a step whose loss is not finite as `failed`."""
+    import repro.train.loop as loop_mod
+    make = loop_mod.make_train_step
+
+    def make_marked(*a, **kw):
+        step = make(*a, **kw)
+
+        def marked(state, scale_state, batch, key):
+            out, metrics = step(state, scale_state, batch, key)
+            i = state.loss_scale.step
+            odd = jnp.logical_and(i >= 4, i % 2 == 1)
+            if mark == "drop":
+                metrics = dict(metrics, grads_finite=jnp.logical_and(
+                    metrics["grads_finite"], jnp.logical_not(odd)))
+            else:
+                metrics = dict(metrics, loss=jnp.where(
+                    odd, jnp.nan, metrics["loss"]))
+            return out, metrics
+        return marked
+    monkeypatch.setattr(loop_mod, "make_train_step", make_marked)
+    result, checks, _ = run_tiny("train")
+    n = result["attempted"]
+    assert n >= 2, result
+    marked = n // 2
+    if mark == "drop":
+        assert result["failed"] == 0 and result["dropped"] == marked, result
+        assert result["correct"], checks
+    else:
+        assert result["failed"] == marked and result["dropped"] == 0, result

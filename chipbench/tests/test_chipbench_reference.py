@@ -1,6 +1,6 @@
 """The plain reference: independent of the program, equal to it where both
-compute in full precision, and its blockwise training step equal to plain
-autodiff."""
+compute in full precision, its blockwise training step equal to plain
+autodiff, and the same whether the layers come as one group or several."""
 import ast
 import os
 
@@ -9,20 +9,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, weights, workload
+from bench import arch, reference, weights, workload
 from conftest import CHIPBENCH, tiny_cell
 
-INDEPENDENT = ("reference.py", "weights.py", "compare.py", "counts.py",
-               "workload.py", "trace.py", "peaks.py")
+QWEN2 = arch.by_name("Qwen2ForCausalLM")
+INDEPENDENT = [os.path.join("bench", n) for n in (
+    "arch.py", "reference.py", "weights.py", "compare.py", "counts.py",
+    "workload.py", "trace.py", "peaks.py")] + sorted(
+    os.path.join("models", n) for n in os.listdir(
+        os.path.join(CHIPBENCH, "models")) if n.endswith(".py"))
 
 
 @pytest.mark.parametrize("name", INDEPENDENT)
 def test_yardstick_imports_nothing_of_the_program(name):
-    tree = ast.parse(open(os.path.join(CHIPBENCH, "bench", name)).read())
-    mods = [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+    """Nothing imports the program, but an architecture's program-side
+    functions (`arch.PROGRAM_SIDE`) inside their bodies."""
+    tree = ast.parse(open(os.path.join(CHIPBENCH, name)).read())
+    allowed = {id(n) for f in tree.body if isinstance(f, ast.FunctionDef)
+               and f.name in arch.PROGRAM_SIDE and name.startswith("models")
+               for n in ast.walk(f)}
+    nodes = [n for n in ast.walk(tree) if id(n) not in allowed]
+    mods = [a.name for n in nodes if isinstance(n, ast.Import)
             for a in n.names]
-    mods += [n.module or "" for n in ast.walk(tree)
-             if isinstance(n, ast.ImportFrom)]
+    mods += [n.module or "" for n in nodes if isinstance(n, ast.ImportFrom)]
     assert not [m for m in mods if m.split(".")[0] == "repro"]
 
 
@@ -35,12 +44,12 @@ def test_int4_grid():
 
 
 def ref_logits(m, w, toks, bits=None):
-    z = weights.dims(m)
+    z = QWEN2.dims(m)
     pos = jnp.arange(len(toks))[None]
     x = w["embed"][jnp.asarray(toks)[None]]
     for i in range(z["L"]):
-        x = reference.layer(reference.layer_params(w, i), x, pos, z,
-                            bits=bits)
+        x = QWEN2.layer(reference.layer_params(w["layers"], i), x, pos, z,
+                        bits=bits)
     hm, spec = reference.head_matrix(w)
     y = reference.rmsnorm(x[0], w["final_norm"], z["eps"])
     return reference.mm(spec, y, hm, None)
@@ -55,14 +64,14 @@ def tiny():
 def test_reference_matches_the_program_at_full_precision(tiny):
     """The program's XLA forward with quantization off (bf16 compute) and
     the reference (f32) agree on logits within bf16 rounding."""
-    from bench.train import program_config, to_program
+    from bench.train import to_program
     from repro.models.transformer import forward
     m, w = tiny
-    cfg = program_config(m)
+    cfg = QWEN2.program_config(m)
     cfg = cfg.replace(policy=cfg.policy.__class__(
         quant=cfg.policy.quant.baseline(), master_weight_dtype="float32"))
     toks = np.random.default_rng(0).integers(0, m["vocab_size"], 48)
-    prog = forward(to_program(w, cfg), jnp.asarray(toks[None], jnp.int32),
+    prog = forward(to_program(w, m, cfg), jnp.asarray(toks[None], jnp.int32),
                    cfg=cfg)[0][0].astype(jnp.float32)
     ref = ref_logits(m, w, toks)
     scale = float(jnp.std(ref))
@@ -78,7 +87,7 @@ def test_reference_matches_the_program_at_full_precision(tiny):
 
 def test_blockwise_training_step_equals_autodiff(tiny):
     m, w = tiny
-    z = weights.dims(m)
+    z = QWEN2.dims(m)
     b = workload.train_batches(1, vocab=m["vocab_size"], batch=2, seq=32,
                                n=1, temperature=0.3)[0]
     pos = jnp.broadcast_to(jnp.arange(32), (2, 32))
@@ -86,7 +95,8 @@ def test_blockwise_training_step_equals_autodiff(tiny):
     def loss(w):
         x = w["embed"][b["tokens"]]
         for i in range(z["L"]):
-            x = reference.layer(reference.layer_params(w, i), x, pos, z)
+            x = QWEN2.layer(reference.layer_params(w["layers"], i), x, pos,
+                            z)
         y = reference.rmsnorm(x, w["final_norm"], z["eps"])
         lg = jnp.einsum("bsd,vd->bsv", y, w["embed"],
                         precision=jax.lax.Precision.HIGHEST)
@@ -99,13 +109,13 @@ def test_blockwise_training_step_equals_autodiff(tiny):
                                "eps": 1e-8}, rows=16, q_block=16)
     got = {}
 
-    def on_grad(n, i, g):
+    def on_grad(group, n, i, g):
         got[(n, i)] = np.asarray(g)
 
     st = tr.init_state(jax.tree_util.tree_map(jnp.array, w))
     assert tr.step(st, b, on_grad=on_grad) == pytest.approx(
         float(want_loss), rel=1e-5)
-    for n in weights.LAYER_LEAVES:
+    for n in w["layers"]:
         for i in range(z["L"]):
             np.testing.assert_allclose(got[(n, i)], want["layers"][n][i],
                                        rtol=2e-3, atol=1e-6)
@@ -128,7 +138,7 @@ def test_kept_step_equals_the_plain_step_and_a_drop_changes_nothing(tiny):
         loss = tr.step(st, b, decide=decide)
         out[name] = (loss, st)
     assert out["now"][0] == out["held"][0] == out["dropped"][0]
-    for n in weights.LAYER_LEAVES:
+    for n in w["layers"]:
         np.testing.assert_array_equal(out["now"][1]["w"]["layers"][n],
                                       out["held"][1]["w"]["layers"][n])
         np.testing.assert_array_equal(out["dropped"][1]["w"]["layers"][n],
@@ -140,22 +150,22 @@ def test_kept_step_equals_the_plain_step_and_a_drop_changes_nothing(tiny):
 
 def test_taps_read_the_backward_tensors_and_change_no_gradient(tiny):
     m, w = tiny
-    z = weights.dims(m)
+    z = QWEN2.dims(m)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 32, z["d"]))
     pos = jnp.broadcast_to(jnp.arange(32), (2, 32))
     g = jax.random.normal(jax.random.PRNGKey(4), x.shape)
-    p = reference.layer_params(w, 0)
-    plain = jax.vjp(lambda pp, xx: reference.layer(pp, xx, pos, z,
-                                                   q_block=16), p, x)[1](g)
-    taps = reference.zero_taps(32, 16)
+    p = reference.layer_params(w["layers"], 0)
+    plain = jax.vjp(lambda pp, xx: QWEN2.layer(pp, xx, pos, z, q_block=16),
+                    p, x)[1](g)
+    taps = QWEN2.zero_taps(32, 16)
     assert taps["attn"].shape == (2, 3)
     gp, gx, gt = jax.vjp(
-        lambda pp, xx, tt: reference.layer(pp, xx, pos, z, q_block=16,
-                                           taps=tt), p, x, taps)[1](g)
+        lambda pp, xx, tt: QWEN2.layer(pp, xx, pos, z, q_block=16, taps=tt),
+        p, x, taps)[1](g)
     np.testing.assert_allclose(gx, plain[1], rtol=1e-5, atol=1e-5)
-    amax = np.asarray(reference.tap_amax(gt))
-    site = {s: amax[i] for i, s in enumerate(reference.SITES)}
-    for gemm in reference.GEMMS:
+    amax = np.asarray(QWEN2.tap_amax(gt))
+    site = {s: amax[i] for i, s in enumerate(QWEN2.SITES)}
+    for gemm in QWEN2.GEMMS:
         # a projection's weight is used once: dW is its whole gradient
         np.testing.assert_allclose(gp[gemm], plain[0][gemm], rtol=1e-5,
                                    atol=1e-5)
@@ -166,6 +176,75 @@ def test_taps_read_the_backward_tensors_and_change_no_gradient(tiny):
     # attention's output error is the output projection's input gradient
     assert site["attn.dO"] == pytest.approx(site["wo.dA"], rel=1e-5)
     assert site["attn.dP"] > 0 and site["attn.dS"] > 0
+
+
+SPLIT = """
+
+import dataclasses
+
+_one_group = groups
+
+
+def groups(z):
+    (g,) = _one_group(z)
+    return [dataclasses.replace(g, name="first", depth=1),
+            dataclasses.replace(g, name="rest", depth=g.depth - 1)]
+"""
+
+
+def test_layers_split_into_groups_train_as_one_group(tmp_path, monkeypatch):
+    """The tiny model at three layers, as one group and as groups of one
+    and two layers (the qwen2 module under another name, its `groups`
+    split): the same losses, gradients, taps and updated weights, to f32
+    round-off, over two steps."""
+    _, _, m, _ = tiny_cell("train")
+    m = dict(m, num_hidden_layers=3)
+    src = os.path.join(CHIPBENCH, "models", "Qwen2ForCausalLM.py")
+    (tmp_path / "SplitForCausalLM.py").write_text(
+        open(src).read() + SPLIT)
+    monkeypatch.setattr(arch, "MODELS", tmp_path)
+    split = dict(m, architectures=["SplitForCausalLM"])
+    w = weights.make(6, m, jnp.float32)
+    w_split = {k: v for k, v in w.items() if k != "layers"}
+    w_split["first"] = {n: x[:1] for n, x in w["layers"].items()}
+    w_split["rest"] = {n: x[1:] for n, x in w["layers"].items()}
+    opt = {"lr": 1e-3, "b1": 0.9, "b2": 0.999, "eps": 1e-8}
+    pool = workload.train_batches(3, vocab=m["vocab_size"], batch=2, seq=32,
+                                  n=2, temperature=0.3)
+    first = {"layers": 0, "first": 0, "rest": 1}    # a group's first layer
+    out = {}
+    for name, mm, ww in (("one", m, w), ("split", split, w_split)):
+        tr = reference.Trainer(mm, opt, rows=16, q_block=16)
+        assert [g.name for g in tr.groups] == (
+            ["layers"] if name == "one" else ["first", "rest"])
+        st = tr.init_state(jax.tree_util.tree_map(jnp.array, ww))
+        grads, amax = {}, []
+
+        def on_grad(group, n, i, g):
+            layer = None if i is None else first[group] + i
+            grads[st["count"], n, layer] = np.asarray(g)
+
+        def decide(a):
+            amax.append(a)
+            return True
+        losses = [tr.step(st, b, on_grad=on_grad, decide=decide)
+                  for b in pool]
+        out[name] = (losses, grads, np.stack(amax), tr.site_names, st["w"])
+    one, two = out["one"], out["split"]
+    np.testing.assert_allclose(two[0], one[0], rtol=1e-6)
+    assert two[1].keys() == one[1].keys()
+    for k in one[1]:
+        np.testing.assert_allclose(two[1][k], one[1][k], rtol=1e-5,
+                                   atol=1e-7, err_msg=str(k))
+    assert two[3] == one[3]
+    np.testing.assert_allclose(two[2], one[2], rtol=1e-5, atol=1e-7)
+    for n, x in one[4]["layers"].items():
+        np.testing.assert_allclose(
+            np.concatenate([two[4]["first"][n], two[4]["rest"][n]]), x,
+            rtol=1e-6, atol=1e-7)
+    for n in ("embed", "final_norm"):
+        np.testing.assert_allclose(two[4][n], one[4][n], rtol=1e-6,
+                                   atol=1e-7)
 
 
 RECIPE = {"fmax": 100.0, "margin": 2.0, "growth": 2.0}
@@ -185,7 +264,7 @@ SCALER = {"init": 10.0, "backoff": 0.5, "min": 1.0}
      [True, False, True], [10.0, 10.0, 5.0]),
 ])
 def test_overflow_rule(amaxes, program, verdicts, kept, scales):
-    ovf = reference.Overflow(RECIPE, SCALER, (0.5, 1.5))
+    ovf = reference.Overflow(RECIPE, SCALER, (0.5, 1.5), ["site.0"])
     got = [ovf.decide(np.array([[a]]), program_kept=k)
            for a, k in zip(amaxes, program)]
     assert got == kept
